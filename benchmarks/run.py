@@ -40,6 +40,8 @@ def main() -> None:
                     help="comma-separated section names")
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     failures = []
     for name, mod, desc in SECTIONS:
         if only and name not in only:

@@ -166,8 +166,9 @@ class ServingConfig:
     swap_levels: Tuple[int, ...] = (0, 1, 2, 4, 8, 16)   # bucketed #quantized layers
     swap_bits: int = 4
     # route every swapped-layer matmul through the fused wNa16 kernel path
-    # (kernels/ops.wna16_matmul) instead of dequant-then-matmul
-    use_quant_kernel: bool = False
+    # (kernels/ops.wna16_matmul: Pallas on TPU, the packed-dequant XLA
+    # matmul elsewhere) instead of dequant-then-matmul
+    use_quant_kernel: bool = True
     mode: str = "accuracy"                   # accuracy | performance
     # performance mode swaps earlier and deeper (paper §4 Baselines)
     perf_kv_pressure_high: float = 0.70

@@ -52,6 +52,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List, Optional, Sequence, Union
 
+import jax
 import numpy as np
 
 from repro.configs.base import ModelConfig, ServingConfig
@@ -115,6 +116,7 @@ class ServingCluster:
         self.seed = seed
         self.rng = np.random.default_rng(seed)
         self.fault_plan: Optional[FaultPlan] = None
+        self._placed: Dict = {}          # device -> params placed there
         self.replicas: List[ReplicaState] = [
             ReplicaState(self._make_engine(i)) for i in range(n_replicas)]
         self.pending: List[TraceRequest] = []
@@ -147,10 +149,23 @@ class ServingCluster:
         self.failed_records: List[Request] = []
         self.archived_starvation = 0   # bypass counters of fenced engines
 
+    def _replica_params(self, i: int):
+        """Replica ``i``'s weights on its own device, ``jax.devices()[i]``
+        (wrapping when replicas outnumber devices): each one-chip replica
+        holds its own copy, and its engine serves where its params live.
+        Copies are made once per device and reused on restart."""
+        if self.params is None:
+            return None
+        devs = jax.devices()
+        dev = devs[i % len(devs)]
+        if dev not in self._placed:
+            self._placed[dev] = jax.device_put(self.params, dev)
+        return self._placed[dev]
+
     def _make_engine(self, i: int) -> MorphServeEngine:
         inj = (self.fault_plan.for_replica(i)
                if self.fault_plan is not None else None)
-        e = MorphServeEngine(self.cfg, self.params, self.sc,
+        e = MorphServeEngine(self.cfg, self._replica_params(i), self.sc,
                              dataclasses.replace(self.ec, seed=self.ec.seed + i),
                              fault_injector=inj)
         e.now = self.now

@@ -28,6 +28,21 @@ NVIDIA_L4 = HardwareProfile("l4", 121e12, 300e9, 24 * 2**30)
 NVIDIA_A100_80G = HardwareProfile("a100-80g", 312e12, 2039e9, 80 * 2**30)
 TPU_V5E = HardwareProfile("v5e", 197e12, 819e9, 16 * 2**30)
 PROFILES = {p.name: p for p in (NVIDIA_L4, NVIDIA_A100_80G, TPU_V5E)}
+# ``device_kind`` as JAX reports it -> the chip's profile
+DEVICE_KIND_PROFILES = {"TPU v5 lite": TPU_V5E, "TPU v5e": TPU_V5E}
+
+
+def profile_for_device(device) -> HardwareProfile:
+    """The profile of a TPU from its ``device_kind``. A kind missing from
+    :data:`DEVICE_KIND_PROFILES` is an error, never a default: the clock,
+    the admission estimator and the controller would otherwise run on
+    another chip's peaks."""
+    try:
+        return DEVICE_KIND_PROFILES[device.device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no HardwareProfile for device_kind {device.device_kind!r}; "
+            f"add it to cost_model.DEVICE_KIND_PROFILES") from None
 
 
 def active_params(cfg: ModelConfig) -> int:

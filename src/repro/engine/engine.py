@@ -28,7 +28,8 @@ from repro.core import (KVQuantActuator, MemoryLedger, MorphingActuator,
                         MorphingController, KVResizer, ServingMonitor,
                         Telemetry, build_swap_plan, front_to_back_order)
 from repro.engine import model_exec
-from repro.engine.cost_model import CostModel, HardwareProfile, NVIDIA_L4
+from repro.engine.cost_model import (CostModel, HardwareProfile, NVIDIA_L4,
+                                     profile_for_device)
 from repro.engine.kv_cache import PagedKVPool, PrefixCache, kv_block_bytes
 from repro.engine.metrics import ServingReport, build_report
 from repro.engine.request import (Request, RState, derive_token_seed,
@@ -122,9 +123,12 @@ class KVQuantConfig:
 class EngineConfig:
     policy: str = "morph"            # morph | static_fp16 | static_int4
     compute: str = "real"            # real | sim
-    hw: HardwareProfile = NVIDIA_L4
+    # None: on a TPU the chip's own profile (cost_model.profile_for_device,
+    # unknown kinds raise); off the chip the modeled reference GPU
+    hw: Optional[HardwareProfile] = None
     max_prefills_per_step: int = 2
-    dtype: str = "float32"
+    # KV pool dtype; None follows the model's dtype (ModelConfig.dtype)
+    dtype: Optional[str] = None
     seed: int = 0
     # decode block tables are truncated to the power-of-two bucket of the
     # live max blocks across slots, so per-step gather cost follows the live
@@ -205,6 +209,17 @@ class EngineConfig:
     admission_control: bool = False
 
 
+def resolve_hw(hw: Optional[HardwareProfile], device) -> HardwareProfile:
+    """An explicit profile wins. Otherwise a TPU gets its own profile from
+    its ``device_kind`` (an unknown kind raises), and any other backend —
+    no chip to describe — the modeled reference GPU of the paper."""
+    if hw is not None:
+        return hw
+    if device.platform == "tpu":
+        return profile_for_device(device)
+    return NVIDIA_L4
+
+
 class MorphServeEngine:
     def __init__(self, cfg: ModelConfig, params, serving: ServingConfig,
                  ecfg: EngineConfig, *, swap_order: Optional[Sequence[int]] = None,
@@ -212,6 +227,11 @@ class MorphServeEngine:
         self.cfg = cfg
         self.sc = serving
         self.ec = ecfg
+        # the engine serves on the device its params live on (a cluster
+        # replica's own chip); sim engines have no params and no device work
+        self.device = (next(iter(jax.tree.leaves(params)[0].devices()))
+                       if params is not None else jax.devices()[0])
+        self.kv_dtype = jnp.dtype(ecfg.dtype or cfg.dtype)
         self.now = 0.0
         self.rng = np.random.default_rng(ecfg.seed)
         self.kinds = tuple(lm.layer_kinds(cfg))
@@ -251,7 +271,7 @@ class MorphServeEngine:
         # --- memory ledger + paged pool ------------------------------------
         bs = serving.kv_block_size
         blk_bytes = max(kv_block_bytes(
-            cfg, bs, dtype_bytes=jnp.dtype(ecfg.dtype).itemsize), 1)
+            cfg, bs, dtype_bytes=self.kv_dtype.itemsize), 1)
         w0 = self.plan.weight_bytes(self.actuator.level)
         # non-swappable weights (embeddings/head/norms) live in the reserve
         if ecfg.compute == "sim":
@@ -277,9 +297,19 @@ class MorphServeEngine:
             self.ledger.kv_blocks = start_blocks
         self.resizer = KVResizer(self.ledger, baseline_blocks=baseline_blocks,
                                  step_frac=serving.kv_resize_step_frac)
-        self.pool = PagedKVPool(cfg, start_blocks + 1, bs,
-                                dtype=jnp.dtype(ecfg.dtype),  # +1 scratch
-                                bucket_capacity=ecfg.kv_capacity_bucketing)
+        # the most blocks the budget can ever book in fp: at the lightest
+        # level the policy reaches (+1 scratch); the capacity bucket stops
+        # there
+        levels = (self.plan.levels if self._pinned_level is None
+                  else (self._pinned_level,))
+        most_blocks = self.ledger.max_kv_blocks(
+            min(self.plan.weight_bytes(l) for l in levels)) + 1
+        self.pool = PagedKVPool(cfg, start_blocks + 1, bs,  # +1 scratch
+                                dtype=self.kv_dtype,
+                                bucket_capacity=ecfg.kv_capacity_bucketing,
+                                max_capacity=most_blocks,
+                                device=self.device,
+                                materialize=ecfg.compute == "real")
 
         # --- quantized-KV relief tier ---------------------------------------
         # GQA-family archs only: MLA latent pools and SSM recurrent state
@@ -321,7 +351,8 @@ class MorphServeEngine:
                                              kv_quant=self._kv_quant_on)
         else:
             self.exec = None
-        self.cost = CostModel(cfg, ecfg.hw, block_size=bs)
+        self.cost = CostModel(cfg, resolve_hw(ecfg.hw, self.device),
+                              block_size=bs)
 
         # --- request state ----------------------------------------------------
         self.queue: Deque[Request] = collections.deque()
@@ -371,6 +402,9 @@ class MorphServeEngine:
         self.livelock_failures = 0    # requests FAILED by the preemption cap
         self._step_idx = 0
         self.watchdog_trips: List = []   # (time_s, kind, detail)
+        # landed swaps booked over budget because the pool could not
+        # shrink far enough (see _commit_landed_weights)
+        self.over_budget_landings = 0
         self.watchdog_repairs = 0
 
     # ------------------------------------------------------------------
@@ -1299,27 +1333,8 @@ class MorphServeEngine:
         taken = set(free_low[:len(src)])
         alloc.free = [b for b in alloc.free if b not in taken] + src
         heapq.heapify(alloc.free)
-        if self.ec.compute == "real":
-            si = jnp.array(src, jnp.int32)
-            di = jnp.array([mapping[b] for b in src], jnp.int32)
-            pool = self.pool
-            pool.k = pool.k.at[:, di].set(pool.k[:, si])
-            if self.cfg.mla is None and pool.v.ndim > 1:
-                pool.v = pool.v.at[:, di].set(pool.v[:, si])
-            if pool.qk is not None:
-                # quantized payload + sidecar ride the move; the vacated
-                # rows' scale/zero are zeroed so the freed ids read as pure
-                # fp from the kernels' dequant FMA after realloc
-                pool.qk = pool.qk.at[:, di].set(pool.qk[:, si])
-                if pool.qv.ndim > 1:
-                    pool.qv = pool.qv.at[:, di].set(pool.qv[:, si])
-                for name in ("k_scale", "v_scale", "k_zero", "v_zero"):
-                    a = getattr(pool, name)
-                    a = a.at[:, di].set(a[:, si])
-                    setattr(pool, name, a.at[:, si].set(0.0))
-        for s in src:
-            if s in self.pool.qbits:
-                self.pool.qbits[mapping[s]] = self.pool.qbits.pop(s)
+        # quantized payload + sidecar ride the move (in place on device)
+        self.pool.move_blocks(src, [mapping[b] for b in src])
         self.compaction_moves += len(src)
         return True
 
@@ -1396,6 +1411,12 @@ class MorphServeEngine:
         if n <= 0:
             return
         self.ledger.kv_quant_blocks -= n
+        self._shrink_to_fit()
+
+    def _shrink_to_fit(self) -> None:
+        """Shrink the pool until the ledger fits its budget again; a
+        partial shrink (busy tail) leaves it transiently over budget for
+        the watchdog's shrink-to-fit repair."""
         if self.ledger.ok():
             return
         fit = max(self.ledger.max_kv_blocks(),
@@ -1405,6 +1426,26 @@ class MorphServeEngine:
             if applied is not None:
                 self.ledger.kv_blocks = applied
                 self.resize_log.append((self.now, applied))
+
+    def _commit_landed_weights(self, weight_bytes: int) -> None:
+        """Book a landed swap's weights. A restore is issued only when it
+        fits, but the quantize-cold relief it counted on vanishes if the
+        quantized blocks' owners finish while it is in flight (they
+        restate at fp bytes): the pool first shrinks to what the landed
+        weights leave. When a busy tail stops that shrink short, the
+        weights are booked over budget, the landing is counted in
+        ``over_budget_landings``, and the watchdog's shrink-to-fit repairs
+        the ledger as the tail drains."""
+        fit = max(self.ledger.max_kv_blocks(weight_bytes),
+                  self.ledger.kv_quant_blocks, 1)
+        if fit < self.ledger.kv_blocks:
+            applied = self._shrink_pool(fit)
+            if applied is not None:
+                self.ledger.kv_blocks = applied
+                self.resize_log.append((self.now, applied))
+        self.ledger.weight_bytes = weight_bytes
+        if not self.ledger.ok():
+            self.over_budget_landings += 1
 
     def _reconcile_quant_ledger(self) -> None:
         """Deferred accounting for quantized blocks freed since the last
@@ -1483,7 +1524,7 @@ class MorphServeEngine:
         level_changed = self.actuator.poll(self.now)
         if level_changed:
             self.controller.commit(self.actuator.level)
-            self.ledger.set_weights(self.actuator.weight_bytes())
+            self._commit_landed_weights(self.actuator.weight_bytes())
         sig = self.monitor.signals()
         sig["time_s"] = self.now
         if self.ec.max_tokens_per_step > 0:
@@ -1856,4 +1897,5 @@ class MorphServeEngine:
                             prefix_hit_rate=self.prefix_hit_requests
                             / admitted,
                             prefill_tokens_saved=self.prefill_tokens_saved,
-                            starvation_bypasses=self.starvation_bypasses)
+                            starvation_bypasses=self.starvation_bypasses,
+                            over_budget_landings=self.over_budget_landings)

@@ -16,6 +16,7 @@ elasticity adapted to attention-free models (DESIGN.md §4).
 """
 from __future__ import annotations
 
+import functools
 import heapq
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -99,6 +100,106 @@ class BlockAllocator:
         return True
 
 
+@functools.lru_cache(maxsize=None)
+def _zeros_on(device):
+    """``zeros(shape, dtype)`` computed on ``device`` and committed there.
+    ``jnp.zeros(..., device=d)`` fills the array on the default device and
+    then copies it over: for a replica on another chip that is, for a
+    moment, a second pool on the first replica's chip."""
+    return jax.jit(jnp.zeros, static_argnums=(0, 1),
+                   out_shardings=jax.sharding.SingleDeviceSharding(device))
+
+
+def write_blocks(pool, ids, blocks, layer=None):
+    """``pool[:, ids[j]] = blocks[:, j]`` — or, with ``layer``,
+    ``pool[layer, ids[j]] = blocks[j]`` — one block at a time. On the TPU
+    a scatter or gather of whole ``(bs, KVH, Dh)`` blocks wants the pool in
+    another tiled layout and copies all of it there first (AOT compile for
+    a v5e); a loop of slice updates leaves a donated pool in place."""
+    blocks = blocks.astype(pool.dtype)
+    if layer is not None:
+        blocks = blocks[None]
+    lead = 0 if layer is None else layer
+
+    def put(j, pool):
+        blk = jax.lax.dynamic_slice_in_dim(blocks, j, 1, axis=1)
+        return jax.lax.dynamic_update_slice(
+            pool, blk, (lead, ids[j]) + (0,) * (pool.ndim - 2))
+    return jax.lax.fori_loop(0, ids.shape[0], put, pool)
+
+
+def read_blocks(pool, ids):
+    """``pool[:, ids]`` for a pool ``(L, cap, ...)``, one block at a time
+    (see :func:`write_blocks`): no copy of the pool."""
+    out = jnp.zeros((pool.shape[0], ids.shape[0]) + pool.shape[2:],
+                    pool.dtype)
+
+    def get(j, out):
+        blk = jax.lax.dynamic_slice_in_dim(pool, ids[j], 1, axis=1)
+        return jax.lax.dynamic_update_slice_in_dim(out, blk, j, axis=1)
+    return jax.lax.fori_loop(0, ids.shape[0], get, out)
+
+
+def _read(a, idx):
+    """``a[:, idx]`` for a pool (slice reads) or a sidecar row."""
+    return a[:, idx] if a.ndim == 2 else read_blocks(a, idx)
+
+
+@jax.jit
+def _get_blocks(arrays, idx):
+    """``a[:, idx]`` for every array: the export half of a migration."""
+    return tuple(_read(a, idx) for a in arrays)
+
+
+def _write(a, idx, v):
+    """``a[:, idx] = v`` for a pool ``(L, cap, ...)`` (slice updates) or a
+    sidecar row ``(L, cap)`` (a scatter: a few MB at most)."""
+    v = jnp.broadcast_to(jnp.asarray(v, a.dtype),
+                         (a.shape[0], idx.shape[0]) + a.shape[2:])
+    return a.at[:, idx].set(v) if a.ndim == 2 else write_blocks(a, idx, v)
+
+
+# Device-side pool updates are jitted programs that donate the arrays they
+# rewrite, so XLA updates them in place. An eager ``a.at[idx].set(x)`` on an
+# array the pool still references builds a second array of the full pool's
+# size, and a pool sized to most of HBM has no room for two of itself.
+@functools.partial(jax.jit, donate_argnums=0)
+def _set_blocks(arrays, idx, values):
+    """``arrays[i][:, idx] = values[i]`` for every i, in place."""
+    return tuple(_write(a, idx, v) for a, v in zip(arrays, values))
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _move_blocks(payload, params, src, dst):
+    """Copy blocks ``src`` onto ``dst`` in every array, in place; the
+    ``params`` (sidecar scale/zero) rows of ``src`` are zeroed after."""
+    payload = tuple(write_blocks(a, dst, read_blocks(a, src))
+                    for a in payload)
+    params = tuple(a.at[:, dst].set(a[:, src]).at[:, src].set(0)
+                   for a in params)
+    return payload, params
+
+
+@functools.partial(jax.jit, static_argnames="bits", donate_argnums=(0, 1, 2, 3))
+def _quantize_into(fp, q, scale, zero, idx, *, bits):
+    """Compress blocks ``idx`` of one pool into its sidecar, in place: the
+    int8 payload and per-(layer, block) scale/zero are written, the fp slot
+    zeroed."""
+    qv, s, z = kv_codec.affine_quantize_jnp(read_blocks(fp, idx), bits)
+    return (_write(fp, idx, 0), _write(q, idx, qv), _write(scale, idx, s),
+            _write(zero, idx, z))
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 2, 3))
+def _dequantize_into(fp, q, scale, zero, idx):
+    """Inverse of :func:`_quantize_into`, in place: ``q*scale+zero`` back
+    to the fp slot, scale/zero rows zeroed (the stale payload is inert)."""
+    deq = kv_codec.affine_dequantize_jnp(read_blocks(q, idx), scale[:, idx],
+                                         zero[:, idx], fp.dtype)
+    return (_write(fp, idx, deq), _write(scale, idx, 0),
+            _write(zero, idx, 0))
+
+
 class PagedKVPool:
     """Owns the device pool arrays + allocator.
 
@@ -112,15 +213,24 @@ class PagedKVPool:
     ``log2(max_blocks)`` shapes to the jit cache. ``copies`` counts device
     pool copies for the benchmarks/tests. Disable with
     ``bucket_capacity=False`` to recover the seed's copy-per-resize
-    behaviour (capacity == num_blocks at all times).
+    behaviour (capacity == num_blocks at all times). ``max_capacity`` caps
+    the bucket (never below the logical count): the owner passes the most
+    blocks its budget can ever hold, so rounding up to a power of two never
+    allocates device memory the budget does not have.
     """
 
     def __init__(self, cfg: ModelConfig, num_blocks: int, block_size: int,
-                 dtype=jnp.float32, *, bucket_capacity: bool = True):
+                 dtype=jnp.float32, *, bucket_capacity: bool = True,
+                 max_capacity: Optional[int] = None,
+                 device=None, materialize: bool = True):
         self.cfg = cfg
         self.block_size = block_size
         self.dtype = dtype
         self.bucket_capacity = bucket_capacity
+        self.max_capacity = max_capacity
+        # arrays are made on and committed to ``device`` (None: JAX's
+        # default device, uncommitted)
+        self._zeros = jnp.zeros if device is None else _zeros_on(device)
         L = cfg.n_layers
         if cfg.mla is not None:
             width = cfg.mla.kv_lora_rank + cfg.mla.qk_rope_head_dim
@@ -130,9 +240,14 @@ class PagedKVPool:
         self.capacity = self._cap_bucket(num_blocks)
         self.copies = 0
         shape = (L, self.capacity, block_size, self.kvh, self.dh)
-        self.k = jnp.zeros(shape, dtype)
-        self.v = (jnp.zeros(shape, dtype) if cfg.mla is None
-                  else jnp.zeros((1,), dtype))     # MLA: latent-only pool
+        if materialize:
+            self.k = self._zeros(shape, dtype)
+            self.v = (self._zeros(shape, dtype) if cfg.mla is None
+                      else self._zeros((1,), dtype))   # MLA: latent only
+        else:
+            # simulated compute keeps the block accounting only: a
+            # paper-scale pool is gigabytes no sim path ever reads
+            self.k = self.v = None
         self.alloc = BlockAllocator(num_blocks)
         # quantized-KV sidecar (quantize-cold relief tier), allocated lazily
         # on the first real quantize so quant-off engines pay nothing:
@@ -152,6 +267,8 @@ class PagedKVPool:
         b = 1
         while b < n:
             b *= 2
+        if self.max_capacity is not None:
+            b = min(b, max(n, self.max_capacity))
         return b
 
     @property
@@ -181,11 +298,12 @@ class PagedKVPool:
         if new_num_blocks > old:
             new_cap = self._cap_bucket(new_num_blocks)
             if new_cap > self.capacity:
-                pad = [(0, 0)] * self.k.ndim
-                pad[1] = (0, new_cap - self.capacity)
-                self.k = jnp.pad(self.k, pad)
-                if self.cfg.mla is None:
-                    self.v = jnp.pad(self.v, pad)
+                if self.k is not None:
+                    pad = [(0, 0)] * self.k.ndim
+                    pad[1] = (0, new_cap - self.capacity)
+                    self.k = jnp.pad(self.k, pad)
+                    if self.cfg.mla is None:
+                        self.v = jnp.pad(self.v, pad)
                 self._resize_sidecar(new_cap)
                 self.capacity = new_cap
                 self.copies += 1
@@ -195,9 +313,10 @@ class PagedKVPool:
             return False
         new_cap = self._cap_bucket(new_num_blocks)
         if new_cap < self.capacity:
-            self.k = self.k[:, :new_cap]
-            if self.cfg.mla is None:
-                self.v = self.v[:, :new_cap]
+            if self.k is not None:
+                self.k = self.k[:, :new_cap]
+                if self.cfg.mla is None:
+                    self.v = self.v[:, :new_cap]
             self._resize_sidecar(new_cap)
             self.capacity = new_cap
             self.copies += 1
@@ -236,12 +355,12 @@ class PagedKVPool:
         if self.qk is not None:
             return
         L = self.k.shape[0]
-        self.qk = jnp.zeros(self.k.shape, jnp.int8)
-        self.qv = (jnp.zeros(self.v.shape, jnp.int8) if self.v.ndim > 1
-                   else jnp.zeros((1,), jnp.int8))
-        zeros2 = jnp.zeros((L, self.capacity), jnp.float32)
-        self.k_scale = self.v_scale = zeros2
-        self.k_zero = self.v_zero = zeros2
+        self.qk = self._zeros(self.k.shape, jnp.int8)
+        self.qv = (self._zeros(self.v.shape, jnp.int8) if self.v.ndim > 1
+                   else self._zeros((1,), jnp.int8))
+        # four distinct arrays: the in-place updates donate them one by one
+        self.k_scale, self.v_scale, self.k_zero, self.v_zero = (
+            self._zeros((L, self.capacity), jnp.float32) for _ in range(4))
 
     def mark_quantized(self, ids: Sequence[int], bits: int = 8) -> List[int]:
         """Accounting-only quantize (sim compute): tracks which blocks are
@@ -268,17 +387,11 @@ class PagedKVPool:
             return []
         self._ensure_sidecar()
         idx = jnp.asarray(fresh, jnp.int32)
-        q, s, z = kv_codec.affine_quantize_jnp(self.k[:, idx], bits)
-        self.qk = self.qk.at[:, idx].set(q)
-        self.k_scale = self.k_scale.at[:, idx].set(s)
-        self.k_zero = self.k_zero.at[:, idx].set(z)
-        self.k = self.k.at[:, idx].set(0)
+        self.k, self.qk, self.k_scale, self.k_zero = _quantize_into(
+            self.k, self.qk, self.k_scale, self.k_zero, idx, bits=bits)
         if self.v.ndim > 1:
-            q, s, z = kv_codec.affine_quantize_jnp(self.v[:, idx], bits)
-            self.qv = self.qv.at[:, idx].set(q)
-            self.v_scale = self.v_scale.at[:, idx].set(s)
-            self.v_zero = self.v_zero.at[:, idx].set(z)
-            self.v = self.v.at[:, idx].set(0)
+            self.v, self.qv, self.v_scale, self.v_zero = _quantize_into(
+                self.v, self.qv, self.v_scale, self.v_zero, idx, bits=bits)
         return self.mark_quantized(fresh, bits)
 
     def dequantize_blocks(self, ids: Sequence[int]) -> List[int]:
@@ -288,19 +401,11 @@ class PagedKVPool:
         if not held or self.qk is None:
             return self.mark_dequantized(held)
         idx = jnp.asarray(held, jnp.int32)
-        deq = kv_codec.affine_dequantize_jnp(
-            self.qk[:, idx], self.k_scale[:, idx], self.k_zero[:, idx],
-            self.dtype)
-        self.k = self.k.at[:, idx].set(deq)
-        self.k_scale = self.k_scale.at[:, idx].set(0.0)
-        self.k_zero = self.k_zero.at[:, idx].set(0.0)
+        self.k, self.k_scale, self.k_zero = _dequantize_into(
+            self.k, self.qk, self.k_scale, self.k_zero, idx)
         if self.v.ndim > 1:
-            deq = kv_codec.affine_dequantize_jnp(
-                self.qv[:, idx], self.v_scale[:, idx], self.v_zero[:, idx],
-                self.dtype)
-            self.v = self.v.at[:, idx].set(deq)
-            self.v_scale = self.v_scale.at[:, idx].set(0.0)
-            self.v_zero = self.v_zero.at[:, idx].set(0.0)
+            self.v, self.v_scale, self.v_zero = _dequantize_into(
+                self.v, self.qv, self.v_scale, self.v_zero, idx)
         return self.mark_dequantized(held)
 
     def clear_quant(self, ids: Sequence[int]) -> List[int]:
@@ -312,11 +417,10 @@ class PagedKVPool:
         if not held:
             return []
         if self.qk is not None:
-            idx = jnp.asarray(held, jnp.int32)
-            self.k_scale = self.k_scale.at[:, idx].set(0.0)
-            self.k_zero = self.k_zero.at[:, idx].set(0.0)
-            self.v_scale = self.v_scale.at[:, idx].set(0.0)
-            self.v_zero = self.v_zero.at[:, idx].set(0.0)
+            (self.k_scale, self.k_zero, self.v_scale,
+             self.v_zero) = _set_blocks(
+                (self.k_scale, self.k_zero, self.v_scale, self.v_zero),
+                jnp.asarray(held, jnp.int32), (0.0,) * 4)
         return self.mark_dequantized(held)
 
     def kv_quant_bundle(self):
@@ -331,30 +435,36 @@ class PagedKVPool:
         vz = self.v_zero
         return (self.qk, qv, self.k_scale, vs, self.k_zero, vz)
 
-    def move_block(self, src: int, dst: int) -> None:
-        """Copy block ``src``'s full state (fp slot + quant sidecar + quant
-        map) onto ``dst`` — tail-compaction support. The caller frees
-        ``src`` afterwards (and `clear_quant` keeps its sidecar inert)."""
-        si, di = jnp.int32(src), jnp.int32(dst)
-        self.k = self.k.at[:, di].set(self.k[:, si])
-        if self.v.ndim > 1:
-            self.v = self.v.at[:, di].set(self.v[:, si])
-        if self.qk is not None:
-            self.qk = self.qk.at[:, di].set(self.qk[:, si])
-            if self.qv.ndim > 1:
-                self.qv = self.qv.at[:, di].set(self.qv[:, si])
-            self.k_scale = self.k_scale.at[:, di].set(self.k_scale[:, si])
-            self.v_scale = self.v_scale.at[:, di].set(self.v_scale[:, si])
-            self.k_zero = self.k_zero.at[:, di].set(self.k_zero[:, si])
-            self.v_zero = self.v_zero.at[:, di].set(self.v_zero[:, si])
-        if src in self.qbits:
-            self.qbits[dst] = self.qbits.pop(src)
+    def move_blocks(self, src: Sequence[int], dst: Sequence[int]) -> None:
+        """Copy each block ``src[i]``'s full state (fp slot + quant sidecar
+        + quant map) onto ``dst[i]``, in place — tail-compaction support.
+        The vacated ids' scale/zero rows are zeroed, so they read as pure
+        fp from the kernels' dequant FMA once the caller frees and
+        reallocates them. Simulated pools move the quant map only."""
+        if self.k is not None and src:
+            si = jnp.asarray(list(src), jnp.int32)
+            di = jnp.asarray(list(dst), jnp.int32)
+            payload = [self.k] + ([self.v] if self.v.ndim > 1 else [])
+            params = []
             if self.qk is not None:
-                idx = jnp.asarray([src], jnp.int32)
-                self.k_scale = self.k_scale.at[:, idx].set(0.0)
-                self.k_zero = self.k_zero.at[:, idx].set(0.0)
-                self.v_scale = self.v_scale.at[:, idx].set(0.0)
-                self.v_zero = self.v_zero.at[:, idx].set(0.0)
+                payload += [self.qk] + ([self.qv] if self.qv.ndim > 1 else [])
+                params = [self.k_scale, self.v_scale, self.k_zero,
+                          self.v_zero]
+            payload, params = _move_blocks(tuple(payload), tuple(params),
+                                           si, di)
+            payload = list(payload)
+            self.k = payload.pop(0)
+            if self.v.ndim > 1:
+                self.v = payload.pop(0)
+            if self.qk is not None:
+                self.qk = payload.pop(0)
+                if self.qv.ndim > 1:
+                    self.qv = payload.pop(0)
+                (self.k_scale, self.v_scale, self.k_zero,
+                 self.v_zero) = params
+        for s, d in zip(src, dst):
+            if s in self.qbits:
+                self.qbits[d] = self.qbits.pop(s)
 
     # ------------------------------------------------------------------
     # cross-replica state transfer (request migration / prefix migration)
@@ -365,18 +475,20 @@ class PagedKVPool:
         latent pools). This is the export half of paged-KV migration — the
         contents travel, the ids do not (the importer allocates its own)."""
         idx = jnp.asarray(list(ids), jnp.int32)
-        k = np.asarray(self.k[:, idx])
-        v = (np.asarray(self.v[:, idx])
-             if self.cfg.mla is None and self.v.ndim > 1 else None)
-        return k, v
+        if self.cfg.mla is None and self.v.ndim > 1:
+            k, v = _get_blocks((self.k, self.v), idx)
+            return np.asarray(k), np.asarray(v)
+        (k,) = _get_blocks((self.k,), idx)
+        return np.asarray(k), None
 
     def scatter_blocks(self, ids: Sequence[int], k, v=None) -> None:
         """Write migrated block contents into freshly-allocated local ids
         (the import half of paged-KV migration)."""
         idx = jnp.asarray(list(ids), jnp.int32)
-        self.k = self.k.at[:, idx].set(jnp.asarray(k, self.dtype))
         if v is not None and self.cfg.mla is None and self.v.ndim > 1:
-            self.v = self.v.at[:, idx].set(jnp.asarray(v, self.dtype))
+            self.k, self.v = _set_blocks((self.k, self.v), idx, (k, v))
+        else:
+            (self.k,) = _set_blocks((self.k,), idx, (k,))
 
     def gather_quant_blocks(self, ids: Sequence[int]):
         """Host copies of the listed *quantized* blocks' compressed state:
@@ -386,12 +498,12 @@ class PagedKVPool:
         assert self.qk is not None and all(b in self.qbits for b in ids), \
             "gather_quant_blocks on non-quantized blocks"
         idx = jnp.asarray(list(ids), jnp.int32)
-        qv = (np.asarray(self.qv[:, idx]) if self.qv.ndim > 1 else None)
-        return (np.asarray(self.qk[:, idx]), qv,
-                np.asarray(self.k_scale[:, idx]),
-                np.asarray(self.v_scale[:, idx]),
-                np.asarray(self.k_zero[:, idx]),
-                np.asarray(self.v_zero[:, idx]))
+        has_v = self.qv.ndim > 1
+        out = [np.asarray(a) for a in _get_blocks(
+            (self.qk, self.k_scale, self.v_scale, self.k_zero, self.v_zero)
+            + ((self.qv,) if has_v else ()), idx)]
+        qk, ks, vs, kz, vz = out[:5]
+        return (qk, out[5] if has_v else None, ks, vs, kz, vz)
 
     def scatter_quant_blocks(self, ids: Sequence[int], payload,
                              bits: int = 8) -> None:
@@ -401,17 +513,13 @@ class PagedKVPool:
         self._ensure_sidecar()
         qk, qv, ks, vs, kz, vz = payload
         idx = jnp.asarray(list(ids), jnp.int32)
-        self.qk = self.qk.at[:, idx].set(jnp.asarray(qk, jnp.int8))
-        self.k_scale = self.k_scale.at[:, idx].set(jnp.asarray(ks, jnp.float32))
-        self.k_zero = self.k_zero.at[:, idx].set(jnp.asarray(kz, jnp.float32))
-        self.k = self.k.at[:, idx].set(0)
+        self.qk, self.k_scale, self.k_zero, self.k = _set_blocks(
+            (self.qk, self.k_scale, self.k_zero, self.k), idx,
+            (qk, ks, kz, 0))
         if qv is not None and self.qv.ndim > 1:
-            self.qv = self.qv.at[:, idx].set(jnp.asarray(qv, jnp.int8))
-            self.v_scale = self.v_scale.at[:, idx].set(
-                jnp.asarray(vs, jnp.float32))
-            self.v_zero = self.v_zero.at[:, idx].set(
-                jnp.asarray(vz, jnp.float32))
-            self.v = self.v.at[:, idx].set(0)
+            self.qv, self.v_scale, self.v_zero, self.v = _set_blocks(
+                (self.qv, self.v_scale, self.v_zero, self.v), idx,
+                (qv, vs, vz, 0))
         self.mark_quantized(list(ids), bits)
 
 

@@ -68,6 +68,9 @@ class ServingReport:
     # scheduler starvation audit (CI-gated zero): aged batch/background
     # candidates bypassed by a later admission in the same round
     starvation_bypasses: int = 0
+    # landed swaps whose weights were booked over the HBM budget because a
+    # busy pool tail stopped the shrink (repaired later by the watchdog)
+    over_budget_landings: int = 0
     # per-class breakdown keyed by class name; values hold n, n_finished,
     # n_shed, n_failed, ttft_p50/p95, slo_attainment (finished within the
     # class TTFT target / all non-FAILED submissions), goodput_tok_s
@@ -105,7 +108,8 @@ def build_report(requests: List[Request], *, ttft_slo_s: float,
                  prefill_tokens_saved: int = 0,
                  n_redispatched: int = 0,
                  n_migrated: int = 0,
-                 starvation_bypasses: int = 0) -> ServingReport:
+                 starvation_bypasses: int = 0,
+                 over_budget_landings: int = 0) -> ServingReport:
     fin = [r for r in requests if r.state == RState.FINISHED]
     failed = sum(1 for r in requests if r.state == RState.FAILED)
     shed = sum(1 for r in requests if r.state == RState.SHED)
@@ -180,4 +184,5 @@ def build_report(requests: List[Request], *, ttft_slo_s: float,
         n_shed=shed,
         goodput_tok_s=goodput_tok / duration_s,
         starvation_bypasses=starvation_bypasses,
+        over_budget_landings=over_budget_landings,
         class_stats=class_stats)

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.engine.kv_cache import write_blocks
 from repro.kernels import dispatch, ops
 from repro.models import layers as L
 from repro.models import lm
@@ -220,11 +221,11 @@ def paged_prefill(cfg: ModelConfig, kinds, misc, layer_params, tokens,
         if "k" in payload and nb > 0:
             k = _block_pad(payload["k"][0])
             v = _block_pad(payload["v"][0])
-            pool_k = pool_k.at[i, block_ids].set(k.astype(pool_k.dtype))
-            pool_v = pool_v.at[i, block_ids].set(v.astype(pool_v.dtype))
+            pool_k = write_blocks(pool_k, block_ids, k, layer=i)
+            pool_v = write_blocks(pool_v, block_ids, v, layer=i)
         elif "latent" in payload and nb > 0:
             lat = _block_pad(payload["latent"][0])[:, :, None, :]
-            pool_k = pool_k.at[i, block_ids].set(lat.astype(pool_k.dtype))
+            pool_k = write_blocks(pool_k, block_ids, lat, layer=i)
         if "ssm_conv" in payload:
             ssm_conv = ssm_conv.at[ssm_li, slot].set(payload["ssm_conv"][0])
             ssm_ssm = ssm_ssm.at[ssm_li, slot].set(payload["ssm_ssm"][0])
@@ -250,14 +251,15 @@ def paged_prefill_batch(cfg: ModelConfig, kinds, misc, layer_params, tokens,
     nb = tables.shape[1]
     for i, payload in enumerate(payloads):
         if "k" in payload and nb > 0:
-            k = payload["k"].reshape(P, nb, bs, *payload["k"].shape[2:])
-            v = payload["v"].reshape(P, nb, bs, *payload["v"].shape[2:])
-            pool_k = pool_k.at[i, tables].set(k.astype(pool_k.dtype))
-            pool_v = pool_v.at[i, tables].set(v.astype(pool_v.dtype))
+            ids = tables.reshape(-1)
+            k = payload["k"].reshape(P * nb, bs, *payload["k"].shape[2:])
+            v = payload["v"].reshape(P * nb, bs, *payload["v"].shape[2:])
+            pool_k = write_blocks(pool_k, ids, k, layer=i)
+            pool_v = write_blocks(pool_v, ids, v, layer=i)
         elif "latent" in payload and nb > 0:
             lat = payload["latent"].reshape(
-                P, nb, bs, *payload["latent"].shape[2:])[:, :, :, None, :]
-            pool_k = pool_k.at[i, tables].set(lat.astype(pool_k.dtype))
+                P * nb, bs, *payload["latent"].shape[2:])[:, :, None, :]
+            pool_k = write_blocks(pool_k, tables.reshape(-1), lat, layer=i)
     last = logits[jnp.arange(P), lens - 1]
     return last, pool_k, pool_v
 

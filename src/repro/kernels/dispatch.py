@@ -74,6 +74,24 @@ def interpret(m: str = None, backend: str = None) -> bool:
     return resolve(m, backend) == "pallas_interpret"
 
 
+def kernel_interpret(interpret: bool = None) -> bool:
+    """The ``interpret`` flag of a direct call to a Pallas kernel entry
+    point. An explicit flag wins; ``None`` resolves the dispatch mode:
+    compiled under ``pallas`` (``auto`` on TPU), interpreted under
+    ``pallas_interpret``. Under ``xla`` there is no kernel to run — the XLA
+    path of each family lives behind :mod:`repro.kernels.ops`, so asking a
+    kernel entry point for it is an error rather than a silent interpret."""
+    if interpret is not None:
+        return interpret
+    m = resolve()
+    if m == "xla":
+        raise ValueError(
+            "REPRO_QUANT_KERNEL resolves to 'xla', which has no Pallas "
+            "kernel; call the repro.kernels.ops entry point for the XLA "
+            "path, or pass interpret= explicitly")
+    return m == "pallas_interpret"
+
+
 def kv_dequant_path(m: str = None, backend: str = None) -> str:
     """Where quantized-KV blocks get dequantized for the resolved mode.
 

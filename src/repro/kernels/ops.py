@@ -131,14 +131,23 @@ def wna16_matmul(x2, qt, *, bias=None):
 def paged_attention(q, k_pool, v_pool, block_tables, context_lens,
                     spec: AttentionSpec = None, *,
                     kv_quant=None, window: int = 0, softcap: float = 0.0):
-    """Context-only decode read (no append); thin wrapper over the block
-    walk. All static parameters come from ``spec``; ``kv_quant`` is the
-    optional quantized-KV sidecar bundle for this layer's pool."""
+    """Context-only decode read (no append): the block walk under the
+    Pallas modes, the bucketed jnp gather under ``xla``. All static
+    parameters come from ``spec``; ``kv_quant`` is the optional
+    quantized-KV sidecar bundle for this layer's pool."""
     spec = _spec_of(spec, window, softcap)
+    mode = dispatch.resolve()
+    if mode == "xla":
+        # the query is the newest pool token, at context_lens - 1
+        return pa.paged_gather_attention(q, k_pool, v_pool, block_tables,
+                                         context_lens - 1,
+                                         window=spec.window,
+                                         softcap=spec.softcap,
+                                         scale=spec.scale, kv_quant=kv_quant)
     return pa.paged_attention(q, k_pool, v_pool, block_tables, context_lens,
                               window=spec.window, softcap=spec.softcap,
                               scale=spec.scale,
-                              interpret=dispatch.resolve() != "pallas",
+                              interpret=(mode == "pallas_interpret"),
                               kv_quant=kv_quant)
 
 
@@ -163,7 +172,7 @@ def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, block_tables,
         return pa.paged_gather_attention(q, k_pool, v_pool, block_tables,
                                          pos, window=spec.window,
                                          softcap=spec.softcap,
-                                         kv_quant=kv_quant)
+                                         scale=spec.scale, kv_quant=kv_quant)
     return pa.paged_attention_fused(q, k_new, v_new, k_pool, v_pool,
                                     block_tables, pos, window=spec.window,
                                     softcap=spec.softcap, scale=spec.scale,

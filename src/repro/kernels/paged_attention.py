@@ -8,9 +8,17 @@ pick which physical pool block to DMA into VMEM next, which is the TPU-native
 equivalent of PagedAttention's pointer indirection (vLLM) and what KVResizer's
 elastic pool relies on.
 
-Grid: (batch, kv_heads, max_blocks_per_seq), innermost = block walk with an
-online-softmax accumulator in VMEM scratch. GQA: the G = H/KVH query heads of
-a kv head are processed together as the (G, Dh) q block.
+Grid: (batch, max_blocks_per_seq), innermost = block walk with an
+online-softmax accumulator per kv head in VMEM scratch. Each grid step DMAs
+one whole pool block, ``(1, block_size, KVH, Dh)`` — every kv head of the
+block at once — and loops over the heads inside the kernel. The block's last
+two dims equal the pool's ``(KVH, Dh)``, which is what the TPU tiling rule
+accepts for any head count; a per-head ``(1, block_size, 1, Dh)`` block puts
+a size-1 block on the second-minor axis and is refused by the v5e compiler.
+For a bf16 pool with KVH=2, XLA tiles the last two dims ``T(2,128)``, so
+the layout costs no padding in HBM and reaches the kernel without a copy.
+GQA: the G = H/KVH query heads of a kv head are processed together as the
+(G, Dh) q block.
 
 Feature parity with ``layers.naive_attention`` for the decode case:
 sliding-window masking (``window``), logit softcapping (``softcap``), and a
@@ -34,24 +42,26 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import dispatch
 
-def _paged_attn_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref,
-                       kn_ref, vn_ref, *rest,
-                       block_size: int, max_nb: int, scale: float,
-                       window: int, softcap: float, fused_new: bool,
-                       kv_quant: bool = False):
-    # quantized-KV sidecar refs ride between the fused-new operands and the
-    # output: per-pool-block int8 mirrors plus per-(block) scale/zero rows.
-    # Dequant is a branch-free FMA (wNa16-epilogue style): a quantized block
-    # has its fp slot zeroed, an fp block has scale=0/zero=0, so
+
+def _paged_attn_kernel(tables_ref, lens_ref, *refs,
+                       block_size: int, max_nb: int, kv_heads: int,
+                       scale: float, window: int, softcap: float,
+                       fused_new: bool, kv_quant: bool = False):
+    # the quantized-KV sidecar (see _walk_call) adds the int8 pool mirrors
+    # and the row's per-table-entry (k_scale, v_scale, k_zero, v_zero) in
+    # SMEM after the fused-new operands. Dequant is a branch-free FMA
+    # (wNa16-epilogue style): a quantized block has its fp slot zeroed, an
+    # fp block has scale=0/zero=0, so
     #   k = fp_slot + q * scale + zero
     # is exact for both without a per-block flag operand.
-    if kv_quant:
-        qk_ref, qv_ref, ks_ref, vs_ref, kz_ref, vz_ref = rest[:6]
-        rest = rest[6:]
-    o_ref, m_scr, l_scr, acc_scr = rest
+    (q_ref, k_ref, v_ref, kn_ref, vn_ref, qk_ref, qv_ref, qp_ref, o_ref,
+     m_scr, l_scr, acc_scr) = _split_refs(refs, kv_quant)
     b = pl.program_id(0)
-    nb = pl.program_id(2)
+    nb = pl.program_id(1)
+    if kv_quant:
+        ks, vs, kz, vz = (qp_ref[0, i, nb] for i in range(4))
 
     @pl.when(nb == 0)
     def _init():
@@ -72,126 +82,139 @@ def _paged_attn_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref,
 
     @pl.when(valid)
     def _step():
-        q = q_ref[0, 0].astype(jnp.float32)     # (G, Dh)
-        k = k_ref[0, :, 0].astype(jnp.float32)  # (bs, Dh)
-        v = v_ref[0, :, 0].astype(jnp.float32)  # (bs, Dh)
-        if kv_quant:
-            k = k + qk_ref[0, :, 0].astype(jnp.float32) * ks_ref[0, 0] \
-                + kz_ref[0, 0]
-            v = v + qv_ref[0, :, 0].astype(jnp.float32) * vs_ref[0, 0] \
-                + vz_ref[0, 0]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        if softcap:
-            s = jnp.tanh(s / softcap) * softcap
         pos = base + jax.lax.broadcasted_iota(jnp.int32, (1, block_size), 1)
         msk = pos < ctx_len                      # (1, bs)
         if window > 0:
             msk &= pos > (qpos - window)
-        s = jnp.where(msk, s, -1e30)             # (G, bs)
-        m_prev = m_scr[...]                      # (G, 1)
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + p.sum(axis=-1, keepdims=True)
-        m_scr[...] = m_new
-        acc_scr[...] = acc_scr[...] * corr + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
+        for h in range(kv_heads):
+            q = q_ref[0, h].astype(jnp.float32)         # (G, Dh)
+            k = k_ref[0, :, h, :].astype(jnp.float32)   # (bs, Dh)
+            v = v_ref[0, :, h, :].astype(jnp.float32)   # (bs, Dh)
+            if kv_quant:
+                k = k + qk_ref[0, :, h, :].astype(jnp.float32) * ks + kz
+                v = v + qv_ref[0, :, h, :].astype(jnp.float32) * vs + vz
+            s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+            if softcap:
+                s = jnp.tanh(s / softcap) * softcap
+            s = jnp.where(msk, s, -1e30)             # (G, bs)
+            m_prev = m_scr[h]                        # (G, 1)
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_scr[h] = l_scr[h] * corr + p.sum(axis=-1, keepdims=True)
+            m_scr[h] = m_new
+            acc_scr[h] = acc_scr[h] * corr + jnp.dot(
+                p, v, preferred_element_type=jnp.float32)
 
     @pl.when(nb == max_nb - 1)
     def _finish():
-        if fused_new:
-            # fold the current token in (always visible: kpos == qpos).
-            q = q_ref[0, 0].astype(jnp.float32)       # (G, Dh)
-            kn = kn_ref[0, 0].astype(jnp.float32)     # (1, Dh)
-            vn = vn_ref[0, 0].astype(jnp.float32)     # (1, Dh)
-            s = jnp.dot(q, kn.T, preferred_element_type=jnp.float32) * scale
-            if softcap:
-                s = jnp.tanh(s / softcap) * softcap   # (G, 1)
-            m_prev = m_scr[...]
-            m_new = jnp.maximum(m_prev, s)
-            p = jnp.exp(s - m_new)
-            corr = jnp.exp(m_prev - m_new)
-            l_scr[...] = l_scr[...] * corr + p
-            m_scr[...] = m_new
-            acc_scr[...] = acc_scr[...] * corr + jnp.dot(
-                p, vn, preferred_element_type=jnp.float32)
-        o_ref[0, 0] = (acc_scr[...] /
-                       jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
+        for h in range(kv_heads):
+            if fused_new:
+                # fold the current token in (always visible: kpos == qpos).
+                q = q_ref[0, h].astype(jnp.float32)       # (G, Dh)
+                kn = kn_ref[0, h].astype(jnp.float32)     # (1, Dh)
+                vn = vn_ref[0, h].astype(jnp.float32)     # (1, Dh)
+                s = jnp.dot(q, kn.T, preferred_element_type=jnp.float32) \
+                    * scale
+                if softcap:
+                    s = jnp.tanh(s / softcap) * softcap   # (G, 1)
+                m_prev = m_scr[h]
+                m_new = jnp.maximum(m_prev, s)
+                p = jnp.exp(s - m_new)
+                corr = jnp.exp(m_prev - m_new)
+                l_scr[h] = l_scr[h] * corr + p
+                m_scr[h] = m_new
+                acc_scr[h] = acc_scr[h] * corr + jnp.dot(
+                    p, vn, preferred_element_type=jnp.float32)
+            o_ref[0, h] = (acc_scr[h] /
+                           jnp.maximum(l_scr[h], 1e-30)).astype(o_ref.dtype)
 
 
-def _quant_specs(bs, Dh):
-    """BlockSpecs for the six quantized-KV sidecar operands: int8 pool
-    mirrors (walked exactly like k_pool/v_pool) and per-block scale/zero
-    rows, each a (1, 1) block picked by the scalar-prefetched table."""
-    pool = pl.BlockSpec((1, bs, 1, Dh),
-                        lambda b, h, nb, tables, lens: (tables[b, nb], 0, h, 0))
-    row = pl.BlockSpec((1, 1),
-                       lambda b, h, nb, tables, lens: (tables[b, nb], 0))
-    return [pool, pool, row, row, row, row]
+def _split_refs(refs, kv_quant):
+    """Name a kernel's refs after (tables, lens/pos0): with ``kv_quant`` the
+    sidecar adds the two int8 mirrors and the scale/zero rows after the
+    fused-new operands; without it those three are None."""
+    if kv_quant:
+        q, k, v, kn, vn, qk, qv, qp, *rest = refs
+    else:
+        qk = qv = qp = None
+        q, k, v, kn, vn, *rest = refs
+    return (q, k, v, kn, vn, qk, qv, qp, *rest)
 
 
-def _quant_operands(kv_quant):
-    """Normalize the (qk, qv, ks, vs, kz, vz) bundle: scale/zero vectors
-    become (num_blocks, 1) so a (1, 1) BlockSpec can walk them."""
-    qk, qv, ks, vs, kz, vz = kv_quant
-    return (qk, qv, ks.reshape(-1, 1), vs.reshape(-1, 1),
-            kz.reshape(-1, 1), vz.reshape(-1, 1))
+def _pool_spec(bs, kvh, dh):
+    """One whole pool block, every kv head: ``tables[b, nb]`` picks it."""
+    return pl.BlockSpec((1, bs, kvh, dh),
+                        lambda b, nb, tables, *_: (tables[b, nb], 0, 0, 0))
+
+
+def _row_spec(shape):
+    """A per-batch-row operand (q, new KV, output): fetched once per row and
+    held across the block walk."""
+    return pl.BlockSpec((1,) + shape, lambda b, nb, *_: (b, 0, 0, 0))
+
+
+def _walk_call(kernel, *, grid, prefetch, in_specs, operands, kv_quant,
+               out_shape, scratch_shapes, interpret, name,
+               vmem_limit_bytes=None):
+    """The block walk's ``pallas_call``: tables and lens/pos0 are
+    scalar-prefetched. The quantized-KV sidecar, when given, adds its int8
+    pool mirrors as two more walked inputs and each batch row's
+    (k_scale, v_scale, k_zero, v_zero) for every table entry as a
+    ``(4, max_nb)`` SMEM row block, gathered through the tables outside the
+    kernel: a (1, 1) VMEM block per pool block is refused by the TPU tiling
+    rule, and a pool-wide scalar-prefetch row outgrows the 1 MiB of SMEM
+    at 65536 blocks, where this row grows with the table only."""
+    bs, kvh, dh = operands[1].shape[1:]
+    if kv_quant is not None:
+        qk, qv, ks, vs, kz, vz = kv_quant
+        tables = prefetch[0]
+        rows = jnp.stack([ks, vs, kz, vz]).astype(jnp.float32)[:, tables]
+        in_specs = in_specs + [_pool_spec(bs, kvh, dh)] * 2 + [
+            pl.BlockSpec((1, 4, tables.shape[1]), lambda b, nb, *_: (b, 0, 0),
+                         memory_space=pltpu.SMEM)]
+        operands = list(operands) + [qk, qv, rows.transpose(1, 0, 2)]
+    return pl.pallas_call(
+        functools.partial(kernel, kv_quant=kv_quant is not None),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch), grid=grid, in_specs=in_specs,
+            out_specs=_row_spec(out_shape.shape[1:]),
+            scratch_shapes=scratch_shapes),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes),
+        interpret=dispatch.kernel_interpret(interpret),
+        name=name,
+    )(*prefetch, *operands)
 
 
 def _paged_call(qg, k_pool, v_pool, k_new, v_new, block_tables, context_lens,
                 *, window, softcap, fused_new, interpret, scale=None,
                 kv_quant=None):
     B, KVH, G, Dh = qg.shape
-    num_blocks, bs = k_pool.shape[:2]
+    bs = k_pool.shape[1]
     max_nb = block_tables.shape[1]
     scale = Dh ** -0.5 if scale is None else scale
 
-    in_specs = [
-        pl.BlockSpec((1, 1, G, Dh),
-                     lambda b, h, nb, tables, lens: (b, h, 0, 0)),
-        pl.BlockSpec((1, bs, 1, Dh),
-                     lambda b, h, nb, tables, lens:
-                     (tables[b, nb], 0, h, 0)),
-        pl.BlockSpec((1, bs, 1, Dh),
-                     lambda b, h, nb, tables, lens:
-                     (tables[b, nb], 0, h, 0)),
-        pl.BlockSpec((1, 1, 1, Dh),
-                     lambda b, h, nb, tables, lens: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, 1, Dh),
-                     lambda b, h, nb, tables, lens: (b, h, 0, 0)),
-    ]
-    operands = [qg, k_pool, v_pool, k_new, v_new]
-    if kv_quant is not None:
-        in_specs += _quant_specs(bs, Dh)
-        operands += list(_quant_operands(kv_quant))
-
-    grid = (B, KVH, max_nb)
-    return pl.pallas_call(
+    return _walk_call(
         functools.partial(_paged_attn_kernel, block_size=bs, max_nb=max_nb,
-                          scale=scale, window=window, softcap=softcap,
-                          fused_new=fused_new, kv_quant=kv_quant is not None),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, 1, G, Dh),
-                                   lambda b, h, nb, tables, lens: (b, h, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((G, 1), jnp.float32),
-                pltpu.VMEM((G, 1), jnp.float32),
-                pltpu.VMEM((G, Dh), jnp.float32),
-            ],
-        ),
+                          kv_heads=KVH, scale=scale, window=window,
+                          softcap=softcap, fused_new=fused_new),
+        grid=(B, max_nb), prefetch=(block_tables, context_lens),
+        in_specs=[_row_spec((KVH, G, Dh)), _pool_spec(bs, KVH, Dh),
+                  _pool_spec(bs, KVH, Dh), _row_spec((KVH, 1, Dh)),
+                  _row_spec((KVH, 1, Dh))],
+        operands=[qg, k_pool, v_pool, k_new, v_new], kv_quant=kv_quant,
         out_shape=jax.ShapeDtypeStruct((B, KVH, G, Dh), qg.dtype),
-        interpret=interpret,
-    )(block_tables, context_lens, *operands)
+        scratch_shapes=[pltpu.VMEM((KVH, G, 1), jnp.float32),
+                        pltpu.VMEM((KVH, G, 1), jnp.float32),
+                        pltpu.VMEM((KVH, G, Dh), jnp.float32)],
+        interpret=interpret, name="paged_decode_attention")
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("window", "softcap", "scale", "interpret"))
 def paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
                     window: int = 0, softcap: float = 0.0,
-                    scale: float = None, interpret: bool = True,
+                    scale: float = None, interpret: bool = None,
                     kv_quant=None):
     """q: (B, H, Dh); pools: (num_blocks, bs, KVH, Dh);
     block_tables: (B, max_nb) int32; context_lens: (B,) int32 → (B, H, Dh).
@@ -205,6 +228,9 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
     ``(qk, qv, k_scale, v_scale, k_zero, v_zero)`` — int8 pool mirrors plus
     per-block f32 scale/zero vectors — enabling in-kernel dequant of
     quantized blocks during the walk (fp blocks carry zeroed params).
+
+    ``interpret=None`` resolves through :func:`dispatch.kernel_interpret`
+    (compiled under ``pallas``, interpreted under ``pallas_interpret``).
     """
     B, H, Dh = q.shape
     KVH = k_pool.shape[2]
@@ -218,11 +244,9 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
     return out.reshape(B, H, Dh)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("window", "softcap", "scale", "interpret"))
 def paged_attention_fused(q, k_new, v_new, k_pool, v_pool, block_tables,
                           pos, *, window: int = 0, softcap: float = 0.0,
-                          scale: float = None, interpret: bool = True,
+                          scale: float = None, interpret: bool = None,
                           kv_quant=None):
     """Fused decode step: ``pos[b]`` tokens are in the pool and the current
     token's (k_new, v_new) — shape (B, KVH, Dh) — enters the softmax as an
@@ -230,7 +254,7 @@ def paged_attention_fused(q, k_new, v_new, k_pool, v_pool, block_tables,
 
     The caller owns the pool scatter (the append itself); this kernel only
     *reads* positions < pos, so append and attention have no data dependence.
-    ``kv_quant``: see :func:`paged_attention`.
+    ``kv_quant``/``interpret``: see :func:`paged_attention`.
     """
     B, H, Dh = q.shape
     KVH = k_pool.shape[2]
@@ -247,18 +271,19 @@ def paged_attention_fused(q, k_new, v_new, k_pool, v_pool, block_tables,
 # ---------------------------------------------------------------------------
 # chunked-prefill attention: a chunk of queries over partially-paged context
 # ---------------------------------------------------------------------------
-def _chunk_attn_kernel(tables_ref, pos0_ref, q_ref, k_ref, v_ref,
-                       kn_ref, vn_ref, *rest,
-                       block_size: int, max_nb: int, chunk: int, groups: int,
-                       scale: float, window: int, softcap: float, dv: int,
-                       fused_new: bool, kv_quant: bool = False):
+def _chunk_attn_kernel(tables_ref, pos0_ref, *refs,
+                       block_size: int, max_nb: int, kv_heads: int,
+                       chunk: int, groups: int, scale: float, window: int,
+                       softcap: float, dv: int, fused_new: bool,
+                       kv_quant: bool = False):
     """Flash-style causal chunk attention over block-table-paged KV.
 
-    One (batch, kv_head) program walks the sequence's block table with
-    scalar-prefetched indirection (innermost grid dim) and keeps an
-    online-softmax accumulator for all ``chunk * groups`` query rows in
-    VMEM scratch. Query row ``r`` is chunk token ``r // groups`` at
-    absolute position ``pos0 + r // groups``.
+    One batch-row program walks the sequence's block table with
+    scalar-prefetched indirection (innermost grid dim), one whole pool
+    block — every kv head — per step, and keeps an online-softmax
+    accumulator per kv head for all ``chunk * groups`` query rows in VMEM
+    scratch. Query row ``r`` is chunk token ``r // groups`` at absolute
+    position ``pos0 + r // groups``.
 
     ``fused_new=True`` is the multi-token batched-append variant: the block
     walk reads only pool positions ``< pos0`` (the already-paged context at
@@ -281,13 +306,13 @@ def _chunk_attn_kernel(tables_ref, pos0_ref, q_ref, k_ref, v_ref,
     same branch-free FMA as the decode kernel — the chunk's own fused KV is
     always fp, so only the walked context needs the epilogue.
     """
-    if kv_quant:
-        qk_ref, qv_ref, ks_ref, vs_ref, kz_ref, vz_ref = rest[:6]
-        rest = rest[6:]
-    o_ref, m_scr, l_scr, acc_scr = rest
+    (q_ref, k_ref, v_ref, kn_ref, vn_ref, qk_ref, qv_ref, qp_ref, o_ref,
+     m_scr, l_scr, acc_scr) = _split_refs(refs, kv_quant)
     b = pl.program_id(0)
-    nb = pl.program_id(2)
+    nb = pl.program_id(1)
     CG = chunk * groups
+    if kv_quant:
+        ks, vs, kz, vz = (qp_ref[0, i, nb] for i in range(4))
 
     @pl.when(nb == 0)
     def _init():
@@ -309,61 +334,63 @@ def _chunk_attn_kernel(tables_ref, pos0_ref, q_ref, k_ref, v_ref,
 
     @pl.when(valid)
     def _step():
-        q = q_ref[0, 0].astype(jnp.float32)        # (CG, Dh)
-        k = k_ref[0, :, 0].astype(jnp.float32)     # (bs, Dh)
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        if kv_quant:
-            k = k + qk_ref[0, :, 0].astype(jnp.float32) * ks_ref[0, 0] \
-                + kz_ref[0, 0]
-            v = v + qv_ref[0, :, 0].astype(jnp.float32) * vs_ref[0, 0] \
-                + vz_ref[0, 0]
-        v = v[:, :dv]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        if softcap:
-            s = jnp.tanh(s / softcap) * softcap
         kpos = base + jax.lax.broadcasted_iota(jnp.int32, (CG, block_size), 1)
         qi = pos0 + jax.lax.broadcasted_iota(
             jnp.int32, (CG, block_size), 0) // groups
         msk = kpos < pos0 if fused_new else kpos <= qi
         if window > 0:
             msk &= kpos > qi - window
-        s = jnp.where(msk, s, -1e30)
-        m_prev = m_scr[...]                        # (CG, 1)
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        p = jnp.where(msk, jnp.exp(s - m_new), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + p.sum(axis=-1, keepdims=True)
-        m_scr[...] = m_new
-        acc_scr[...] = acc_scr[...] * corr + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
+        for h in range(kv_heads):
+            q = q_ref[0, h].astype(jnp.float32)         # (CG, Dh)
+            k = k_ref[0, :, h, :].astype(jnp.float32)   # (bs, Dh)
+            v = v_ref[0, :, h, :].astype(jnp.float32)
+            if kv_quant:
+                k = k + qk_ref[0, :, h, :].astype(jnp.float32) * ks + kz
+                v = v + qv_ref[0, :, h, :].astype(jnp.float32) * vs + vz
+            v = v[:, :dv]
+            s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+            if softcap:
+                s = jnp.tanh(s / softcap) * softcap
+            s = jnp.where(msk, s, -1e30)
+            m_prev = m_scr[h]                          # (CG, 1)
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            p = jnp.where(msk, jnp.exp(s - m_new), 0.0)
+            corr = jnp.exp(m_prev - m_new)
+            l_scr[h] = l_scr[h] * corr + p.sum(axis=-1, keepdims=True)
+            m_scr[h] = m_new
+            acc_scr[h] = acc_scr[h] * corr + jnp.dot(
+                p, v, preferred_element_type=jnp.float32)
 
     @pl.when(nb == max_nb - 1)
     def _finish():
         if fused_new:
             # fold the chunk's own KV in: key j visible to query i iff
             # j <= i (both at pos0 + ·), then the sliding window.
-            q = q_ref[0, 0].astype(jnp.float32)         # (CG, Dh)
-            kn = kn_ref[0, 0].astype(jnp.float32)       # (C, Dh)
-            vn = vn_ref[0, 0].astype(jnp.float32)[:, :dv]
-            s = jnp.dot(q, kn.T, preferred_element_type=jnp.float32) * scale
-            if softcap:
-                s = jnp.tanh(s / softcap) * softcap     # (CG, C)
             qi = jax.lax.broadcasted_iota(jnp.int32, (CG, chunk), 0) // groups
             kj = jax.lax.broadcasted_iota(jnp.int32, (CG, chunk), 1)
             msk = kj <= qi
             if window > 0:
                 msk &= kj > qi - window
-            s = jnp.where(msk, s, -1e30)
-            m_prev = m_scr[...]
-            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-            p = jnp.where(msk, jnp.exp(s - m_new), 0.0)
-            corr = jnp.exp(m_prev - m_new)
-            l_scr[...] = l_scr[...] * corr + p.sum(axis=-1, keepdims=True)
-            m_scr[...] = m_new
-            acc_scr[...] = acc_scr[...] * corr + jnp.dot(
-                p, vn, preferred_element_type=jnp.float32)
-        o_ref[0, 0] = (acc_scr[...] /
-                       jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
+        for h in range(kv_heads):
+            if fused_new:
+                q = q_ref[0, h].astype(jnp.float32)         # (CG, Dh)
+                kn = kn_ref[0, h].astype(jnp.float32)       # (C, Dh)
+                vn = vn_ref[0, h].astype(jnp.float32)[:, :dv]
+                s = jnp.dot(q, kn.T, preferred_element_type=jnp.float32) \
+                    * scale
+                if softcap:
+                    s = jnp.tanh(s / softcap) * softcap     # (CG, C)
+                s = jnp.where(msk, s, -1e30)
+                m_prev = m_scr[h]
+                m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+                p = jnp.where(msk, jnp.exp(s - m_new), 0.0)
+                corr = jnp.exp(m_prev - m_new)
+                l_scr[h] = l_scr[h] * corr + p.sum(axis=-1, keepdims=True)
+                m_scr[h] = m_new
+                acc_scr[h] = acc_scr[h] * corr + jnp.dot(
+                    p, vn, preferred_element_type=jnp.float32)
+            o_ref[0, h] = (acc_scr[h] /
+                           jnp.maximum(l_scr[h], 1e-30)).astype(o_ref.dtype)
 
 
 def _chunk_call(qg, k_pool, v_pool, kn, vn, block_tables, pos0, *, chunk,
@@ -373,47 +400,22 @@ def _chunk_call(qg, k_pool, v_pool, kn, vn, block_tables, pos0, *, chunk,
     bs = k_pool.shape[1]
     max_nb = block_tables.shape[1]
 
-    in_specs = [
-        pl.BlockSpec((1, 1, CG, Dh),
-                     lambda b, h, nb, tables, pos0: (b, h, 0, 0)),
-        pl.BlockSpec((1, bs, 1, Dh),
-                     lambda b, h, nb, tables, pos0:
-                     (tables[b, nb], 0, h, 0)),
-        pl.BlockSpec((1, bs, 1, Dh),
-                     lambda b, h, nb, tables, pos0:
-                     (tables[b, nb], 0, h, 0)),
-        pl.BlockSpec((1, 1, kn.shape[2], Dh),
-                     lambda b, h, nb, tables, pos0: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, vn.shape[2], Dh),
-                     lambda b, h, nb, tables, pos0: (b, h, 0, 0)),
-    ]
-    operands = [qg, k_pool, v_pool, kn, vn]
-    if kv_quant is not None:
-        in_specs += _quant_specs(bs, Dh)
-        operands += list(_quant_operands(kv_quant))
-
-    grid = (B, KVH, max_nb)
-    return pl.pallas_call(
+    return _walk_call(
         functools.partial(_chunk_attn_kernel, block_size=bs, max_nb=max_nb,
-                          chunk=chunk, groups=groups, scale=scale,
-                          window=window, softcap=softcap, dv=dv,
-                          fused_new=fused_new, kv_quant=kv_quant is not None),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, 1, CG, dv),
-                                   lambda b, h, nb, tables, pos0:
-                                   (b, h, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((CG, 1), jnp.float32),
-                pltpu.VMEM((CG, 1), jnp.float32),
-                pltpu.VMEM((CG, dv), jnp.float32),
-            ],
-        ),
+                          kv_heads=KVH, chunk=chunk, groups=groups,
+                          scale=scale, window=window, softcap=softcap, dv=dv,
+                          fused_new=fused_new),
+        grid=(B, max_nb), prefetch=(block_tables, pos0),
+        in_specs=[_row_spec((KVH, CG, Dh)), _pool_spec(bs, KVH, Dh),
+                  _pool_spec(bs, KVH, Dh), _row_spec((KVH,) + kn.shape[2:]),
+                  _row_spec((KVH,) + vn.shape[2:])],
+        operands=[qg, k_pool, v_pool, kn, vn], kv_quant=kv_quant,
         out_shape=jax.ShapeDtypeStruct((B, KVH, CG, dv), qg.dtype),
-        interpret=interpret,
-    )(block_tables, pos0, *operands)
+        scratch_shapes=[pltpu.VMEM((KVH, CG, 1), jnp.float32),
+                        pltpu.VMEM((KVH, CG, 1), jnp.float32),
+                        pltpu.VMEM((KVH, CG, dv), jnp.float32)],
+        interpret=interpret, name="paged_chunk_attention",
+        vmem_limit_bytes=48 * 2**20)
 
 
 def _chunk_io(q, k_pool):
@@ -429,12 +431,10 @@ def _pos0_vec(pos0, B):
     return jnp.broadcast_to(jnp.asarray(pos0, jnp.int32).reshape(-1), (B,))
 
 
-@functools.partial(jax.jit, static_argnames=("window", "softcap", "scale",
-                                             "dv", "interpret"))
 def paged_chunk_attention(q, k_pool, v_pool, block_tables, pos0, *,
                           window: int = 0, softcap: float = 0.0,
                           scale: float = None, dv: int = None,
-                          interpret: bool = True, kv_quant=None):
+                          interpret: bool = None, kv_quant=None):
     """Pool-read chunk block walk: q (B, C, H, Dh), chunk KV already
     scattered into the pool at the block-table offset. Query i at absolute
     position ``pos0 + i`` sees every pool position ``<= pos0 + i``
@@ -443,7 +443,7 @@ def paged_chunk_attention(q, k_pool, v_pool, block_tables, pos0, *,
 
     ``scale`` overrides the default ``Dh ** -0.5``; ``dv`` < Dh enables the
     MLA latent mode (values = first ``dv`` lanes of the latent pool).
-    ``kv_quant``: see :func:`paged_attention`.
+    ``kv_quant``/``interpret``: see :func:`paged_attention`.
     """
     qg, (B, C, H, KVH, G, Dh) = _chunk_io(q, k_pool)
     dv = dv or Dh
@@ -457,12 +457,10 @@ def paged_chunk_attention(q, k_pool, v_pool, block_tables, pos0, *,
         B, C, H, dv)
 
 
-@functools.partial(jax.jit, static_argnames=("window", "softcap", "scale",
-                                             "dv", "interpret"))
 def paged_chunk_attention_fused(q, k_new, v_new, k_pool, v_pool,
                                 block_tables, pos0, *, window: int = 0,
                                 softcap: float = 0.0, scale: float = None,
-                                dv: int = None, interpret: bool = True,
+                                dv: int = None, interpret: bool = None,
                                 kv_quant=None):
     """Multi-token batched-append chunk walk: the block walk covers only the
     already-paged context (< ``pos0``) and the chunk's own (k_new, v_new) —
@@ -538,7 +536,7 @@ def paged_chunk_gather_attention(q, k_pool, v_pool, block_tables, pos0, *,
 # ---------------------------------------------------------------------------
 def paged_gather_attention(q, k_pool, v_pool, block_tables, pos, *,
                            window: int = 0, softcap: float = 0.0,
-                           kv_quant=None):
+                           scale: float = None, kv_quant=None):
     """Decode attention via gather + dense masked softmax (non-TPU path).
 
     Contract: the pool already holds ``pos[b] + 1`` tokens for row b (the
@@ -557,5 +555,5 @@ def paged_gather_attention(q, k_pool, v_pool, block_tables, pos, *,
     gk = k_pool[block_tables].reshape(B, nb * bs, *k_pool.shape[2:])
     gv = v_pool[block_tables].reshape(B, nb * bs, *v_pool.shape[2:])
     out = naive_attention(q[:, None], gk, gv, causal=True, q_offset=pos,
-                          window=window, softcap=softcap)
+                          window=window, softcap=softcap, scale=scale)
     return out[:, 0]

@@ -27,17 +27,22 @@ Weight layout (matches quant/pack.py):
 
 The K block size is always a multiple of ``group`` (and even, for int4):
 scales/zeros are built at the caller's group size, so shrinking the group to
-fit a block — what this file did before — silently misindexes them. Instead
-``bk`` is resliced to the largest group multiple dividing K.
+fit a block — what this file did before — silently misindexes them. The TPU
+tiling rule adds that a block's second-minor dim is a multiple of 8 or the
+whole axis, so the (bk // group, bn) scale block needs ``bk // group`` to be
+a multiple of 8 — or ``bk == K``, one K block covering every group row.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import dispatch
 
 _BM_CANDIDATES = (8, 16, 32, 64, 128)
 
@@ -51,12 +56,16 @@ def _pick_bm(M: int) -> int:
 
 
 def _pick_bk(K: int, group: int, bits: int, bk: int) -> int:
-    """Largest K block <= ``bk`` that divides K and is a multiple of the
-    quantization group (and of 2 for nibble-packed int4)."""
-    quantum = group
-    if bits == 4 and quantum % 2:
-        quantum *= 2
-    assert K % quantum == 0, (K, group, bits)
+    """Largest K block <= ``bk`` (at least one quantum) that divides K,
+    where the quantum is 8 quantization groups rounded up to a multiple of
+    128 lanes: the (bm, bk) activation block then tiles, the (bk // group,
+    bn) scale/zero blocks hold a multiple of 8 rows, and the int4 packed
+    block (bk // 2, bn) does too. When no quantum divides K the block is
+    all of K (every block dim then equals its array dim)."""
+    assert K % group == 0 and (bits != 4 or K % 2 == 0), (K, group, bits)
+    quantum = math.lcm(8 * group, 128)
+    if K % quantum:
+        return K
     m = K // quantum
     d = max(1, min(bk // quantum, m))
     while m % d:
@@ -66,13 +75,14 @@ def _pick_bk(K: int, group: int, bits: int, bk: int) -> int:
 
 def _dequant_block(w_ref, s_ref, z_ref, bits: int, bk: int, group: int):
     """Unpack + dequantize one (bk, bn) weight block in VMEM."""
+    # Mosaic has no uint8 -> f32 cast; the codes go through int32 (exact)
     if bits == 4:
-        packed = w_ref[...]                        # (bk//2, bn) uint8
+        packed = w_ref[...].astype(jnp.int32)      # (bk//2, bn)
         lo = (packed & 0xF).astype(jnp.float32)
         hi = ((packed >> 4) & 0xF).astype(jnp.float32)
         q = jnp.stack([lo, hi], axis=1).reshape(bk, packed.shape[-1])
     else:                                          # int8
-        q = w_ref[...].astype(jnp.float32)         # (bk, bn)
+        q = w_ref[...].astype(jnp.int32).astype(jnp.float32)   # (bk, bn)
     s = jnp.repeat(s_ref[...], group, axis=0)      # (bk, bn)
     z = jnp.repeat(z_ref[...], group, axis=0)
     return (q - z) * s
@@ -106,30 +116,34 @@ def _wna16_kernel(*refs, bits: int, bk: int, group: int, n_k: int,
         o_ref[...] = acc.astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("bits", "group", "out_dtype",
-                                             "bm", "bn", "bk", "interpret"))
 def wna16_gemm(x, packed, scales, zeros, inv_act=None, bias=None, *,
                bits: int, group: int, out_dtype=None,
-               bm: int = 0, bn: int = 128, bk: int = 512,
+               bm: int = 0, bn: int = 128, bk: int = 2048,
                interpret: bool = None):
     """x: (M, K) × packed int{4,8} (K-packed, N) → (M, N) ``out_dtype``.
 
     ``inv_act`` (K,) and ``bias`` (N,) are optional fused-epilogue operands;
     ``out_dtype`` defaults to ``x.dtype``. M is padded to the auto-selected
-    skinny block; K must be divisible by the resliced ``bk`` (always a group
-    multiple); N is blocked at the largest power-of-two divisor <= ``bn``.
-    ``interpret=None`` resolves through :mod:`repro.kernels.dispatch`:
-    compiled under the ``pallas`` mode (and ``auto`` on TPU), interpret
-    everywhere else.
+    skinny block; K is blocked by :func:`_pick_bk`; N is blocked at the
+    largest power-of-two divisor <= ``bn``. ``interpret=None`` resolves
+    through :func:`dispatch.kernel_interpret` (compiled under ``pallas``,
+    interpreted under ``pallas_interpret``; the XLA path is
+    ``ops.wna16_matmul``'s).
     """
-    if interpret is None:
-        from repro.kernels import dispatch
-        interpret = dispatch.resolve() != "pallas"
+    out_dtype = jnp.dtype(out_dtype or x.dtype)
+    return _wna16_pallas(x, packed, scales, zeros, inv_act, bias, bits=bits,
+                         group=group, out_dtype=out_dtype, bm=bm, bn=bn,
+                         bk=bk, interpret=dispatch.kernel_interpret(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("bits", "group", "out_dtype",
+                                             "bm", "bn", "bk", "interpret"))
+def _wna16_pallas(x, packed, scales, zeros, inv_act, bias, *, bits, group,
+                  out_dtype, bm, bn, bk, interpret):
     M, K = x.shape
     N = scales.shape[-1]
-    out_dtype = jnp.dtype(out_dtype or x.dtype)
     bm = bm or _pick_bm(M)
-    bk = _pick_bk(K, group, bits, min(bk, K))
+    bk = _pick_bk(K, group, bits, bk)
     bn = min(bn, N)
     while N % bn:
         bn //= 2
@@ -166,5 +180,6 @@ def wna16_gemm(x, packed, scales, zeros, inv_act=None, bias=None, *,
         out_shape=jax.ShapeDtypeStruct((Mp, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="wna16_gemm",
     )(*operands)
     return out[:M]

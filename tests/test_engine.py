@@ -106,6 +106,90 @@ def test_pool_bucketing_disabled_matches_seed_behavior():
     assert pool.capacity == 12 and pool.k.shape[1] == 12
 
 
+def test_pool_capacity_bucket_stops_at_max_capacity():
+    """The power-of-two bucket never passes ``max_capacity`` (the most
+    blocks the budget can hold) but always holds the logical count."""
+    cfg = reduced(MORPH_LLAMA2_7B)
+    pool = PagedKVPool(cfg, 17, 4, max_capacity=20)
+    assert pool.capacity == 20 and pool.k.shape[1] == 20
+    assert pool.resize(24)                   # beyond the cap: exact size
+    assert pool.capacity == 24 and pool.k.shape[1] == 24
+    assert pool.resize(9)                    # below it: the bucket again
+    assert pool.capacity == 16
+
+
+# pool update -> the arrays it rewrites
+_POOL_UPDATES = {"scatter": ("k", "v"), "quantize": ("k", "v"),
+                 "dequantize": ("k", "v", "k_scale", "v_zero"),
+                 "move": ("k", "v", "qk", "qv", "k_scale", "v_zero"),
+                 "clear": ("k_scale", "k_zero", "v_scale", "v_zero"),
+                 "scatter_quant": ("k", "v", "qk", "k_scale", "v_zero")}
+
+
+def _pool_update(pool, op):
+    """Run one device-side pool update on blocks 1-2 (filled, then
+    quantized, unless the update is that step); return what it rewrote."""
+    shape = (pool.k.shape[0], 2, pool.block_size, pool.kvh, pool.dh)
+    kv = np.ones(shape, np.float32)
+    if op != "scatter":
+        pool.scatter_blocks([1, 2], kv, kv)
+    if op not in ("scatter", "quantize"):
+        pool.quantize_blocks([1, 2])
+    payload = pool.gather_quant_blocks([1, 2]) if pool.qbits else None
+    before = [getattr(pool, name) for name in _POOL_UPDATES[op]]
+    {"scatter": lambda: pool.scatter_blocks([1, 2], kv, kv),
+     "quantize": lambda: pool.quantize_blocks([1, 2]),
+     "dequantize": lambda: pool.dequantize_blocks([1, 2]),
+     "move": lambda: pool.move_blocks([1, 2], [5, 6]),
+     "clear": lambda: pool.clear_quant([1, 2]),
+     "scatter_quant": lambda: pool.scatter_quant_blocks([5, 6], payload),
+     }[op]()
+    return before
+
+
+@pytest.mark.parametrize("op", sorted(_POOL_UPDATES))
+def test_pool_updates_are_in_place(op):
+    """Every device-side pool update donates the arrays it rewrites (the
+    old buffers are gone, not copied): a pool sized to most of the chip's
+    memory has no room for a second copy of itself."""
+    pool = PagedKVPool(reduced(MORPH_LLAMA2_7B), 8, 4)
+    before = _pool_update(pool, op)
+    assert all(a.is_deleted() for a in before), op
+
+
+def test_engine_pool_capacity_within_budget(model):
+    """The engine caps the pool's capacity bucket at what its budget can
+    ever book, so the device never holds a pool the ledger has no bytes
+    for: a pinned fp16 pool never grows, so its capacity is its size; a
+    morphing pool may grow into the bytes the lightest level frees."""
+    cfg, params = model
+    eng = make_engine(cfg, params, blocks=40, policy="static_fp16")
+    assert eng.pool.capacity == eng.pool.num_blocks < 64
+    eng = make_engine(cfg, params, blocks=40)
+    most = eng.ledger.max_kv_blocks(
+        min(eng.plan.weight_bytes(l) for l in eng.plan.levels)) + 1
+    assert eng.pool.num_blocks <= eng.pool.capacity <= most
+
+
+def test_landed_swap_counts_over_budget_booking(model):
+    """A swap landing whose weights the pool cannot make room for (a busy
+    tail blocks the shrink) is booked and counted; one the pool can make
+    room for shrinks it and stays within budget."""
+    cfg, params = model
+    eng = make_engine(cfg, params, blocks=24, compute="sim")
+    led = eng.ledger
+    extra = 4 * led.kv_block_bytes
+    # a free pool: the landing shrinks the pool by the 4 blocks it needs
+    blocks = led.kv_blocks
+    eng._commit_landed_weights(led.weight_bytes + extra)
+    assert led.ok() and led.kv_blocks == blocks - 4
+    assert eng.over_budget_landings == 0
+    # every block held: nothing can shrink, the landing is booked over
+    assert eng.pool.alloc.alloc(eng.pool.alloc.n_free) is not None
+    eng._commit_landed_weights(led.weight_bytes + extra)
+    assert not led.ok() and eng.over_budget_landings == 1
+
+
 # --------------------------------------------------------------------------
 # end-to-end engine runs (real compute)
 # --------------------------------------------------------------------------

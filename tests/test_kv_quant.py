@@ -95,7 +95,7 @@ def test_pool_move_block_carries_quant_state():
     k0, v0 = _fill(pool, [1])
     pool.quantize_blocks([1], bits=8)
     atol = _quant_atol(pool, [1])
-    pool.move_block(1, 5)
+    pool.move_blocks([1], [5])
     assert pool.qbits == {5: 8}
     assert not np.any(np.asarray(pool.k_scale[:, 1]))   # src rows zeroed
     pool.dequantize_blocks([5])
