@@ -1,0 +1,10 @@
+"""Device: the share of the traced window in which no operation ran on the
+chip, 1 - (union of device-op intervals / traced window). Moves
+``output_tok_per_s``."""
+
+
+def read(ctx):
+    red = ctx.trace
+    if not red or red["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - red["busy_s"] / red["window_s"])
