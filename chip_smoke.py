@@ -73,10 +73,6 @@ def check(cond: bool, what: str) -> None:
         raise SmokeFailure(what)
 
 
-# the duration event JAX records for every XLA backend compile
-BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-
-
 # ---------------------------------------------------------------------------
 # one chip: serve through the engine
 # ---------------------------------------------------------------------------
@@ -135,26 +131,22 @@ def serve_phase(cfg, params, *, seed: int = 0, log=print,
                                            rng.integers(0, cfg.vocab, p)))
              for p, g in requests]
     eng = MorphServeEngine(cfg, params, sc, ec)
-    compiles = [0]
+    log0 = len(eng.compile_log)
     steps = []                   # (wall seconds, compiled during the step)
     step = eng.step
 
-    def on_event(event, _secs, **_kw):
-        compiles[0] += event == BACKEND_COMPILE_EVENT
+    def compiled_since(n):
+        return sum(p == "compile" for _, _, p, _ in eng.compile_log[n:])
 
     def timed_step():
-        c0, t0 = compiles[0], time.perf_counter()
+        n0, t0 = len(eng.compile_log), time.perf_counter()
         dt = step()
         jax.block_until_ready((eng.pool.k, eng.pool.v))
-        steps.append((time.perf_counter() - t0, compiles[0] > c0))
+        steps.append((time.perf_counter() - t0, compiled_since(n0) > 0))
         return dt
     eng.step = timed_step
-    jax.monitoring.register_event_duration_secs_listener(on_event)
     t0 = time.perf_counter()
-    try:
-        eng.run_trace(trace, max_steps=5000)
-    finally:
-        jax.monitoring.unregister_event_duration_listener(on_event)
+    eng.run_trace(trace, max_steps=5000)
     wall = time.perf_counter() - t0
 
     levels = [t.swap_level for t in eng.monitor.history]
@@ -172,7 +164,7 @@ def serve_phase(cfg, params, *, seed: int = 0, log=print,
         "chunk_steps": sum(r.prefill_chunks for r in eng.all_requests),
         "preemptions": sum(r.preemptions for r in eng.all_requests),
         "steps": len(steps),
-        "compiles": compiles[0],
+        "compiles": compiled_since(log0),
         "pool_dtype": str(eng.pool.k.dtype),
     }
     steady = sorted(s for s, c in steps if not c)

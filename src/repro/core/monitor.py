@@ -9,7 +9,10 @@ from __future__ import annotations
 
 import dataclasses
 from collections import deque
-from typing import Deque, Dict, List, Optional
+from typing import TYPE_CHECKING, Deque, Dict, List, Optional
+
+if TYPE_CHECKING:
+    from repro.engine.spans import StepSpans
 
 
 @dataclasses.dataclass
@@ -22,18 +25,12 @@ class Telemetry:
     running: int
     swap_level: int
     step_time_s: float
-    preemptions: int = 0
     # token-budgeted step composition (chunked prefill observability):
     # single-token decodes executed, prompt-chunk tokens packed beside them,
-    # prompt tokens still unpaged across PREFILLING + eligible queued
-    # requests, and the live per-step token budget.
+    # and the live per-step token budget.
     decode_tokens: int = 0
     prefill_tokens: int = 0
-    prefill_backlog_tokens: int = 0
     chunk_budget: int = 0
-    # shared-prefix cache residency (blocks counted in kv_used_blocks that
-    # are idle cached prefixes, reclaimable on demand)
-    prefix_cached_blocks: int = 0
     # class-weighted queue pressure: max over arrived queued requests of
     # wait_s * SLOClass.pressure_weight — interactive backlog counts full
     # weight (escalates morph relief as before), batch/background waits are
@@ -43,6 +40,10 @@ class Telemetry:
     # in int8/int4 form, and the ledger bytes that quantization freed
     kv_quant_blocks: int = 0
     kv_bytes_relieved: int = 0
+    # the step's host spans on the wall clock (engine/spans.py): its start
+    # and end, self seconds per span, first device call and last device
+    # wait; filled in as the step's spans close
+    spans: Optional[StepSpans] = None
 
     @property
     def kv_usage(self) -> float:

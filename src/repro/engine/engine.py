@@ -17,6 +17,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import heapq
+import time
 from typing import Deque, Dict, List, Optional, Sequence
 
 import numpy as np
@@ -32,6 +33,8 @@ from repro.engine.cost_model import (CostModel, HardwareProfile, NVIDIA_L4,
                                      profile_for_device)
 from repro.engine.kv_cache import PagedKVPool, PrefixCache, kv_block_bytes
 from repro.engine.metrics import ServingReport, build_report
+from repro.engine import spans
+from repro.engine.spans import span
 from repro.engine.request import (Request, RState, derive_token_seed,
                                   sim_token)
 from repro.engine.traces import (DEFAULT_SLO_CLASS, SLO_CLASSES, SLOClass,
@@ -351,6 +354,9 @@ class MorphServeEngine:
                                              kv_quant=self._kv_quant_on)
         else:
             self.exec = None
+        # JAX's compile phases per program family, process-wide
+        spans.watch_compiles()
+        self.compile_log = spans.COMPILE_LOG
         self.cost = CostModel(cfg, resolve_hw(ecfg.hw, self.device),
                               block_size=bs)
 
@@ -424,6 +430,7 @@ class MorphServeEngine:
                     orig_max_new_tokens=(-1 if tr.orig_max_new_tokens is None
                                          else tr.orig_max_new_tokens),
                     slo_class=tr.slo_class)
+        r.submit_wall_s = time.perf_counter()
         self._next_rid += 1
         self.all_requests.append(r)
         # reject requests that can never fit (block table or max-grown pool)
@@ -1031,6 +1038,8 @@ class MorphServeEngine:
             self.starvation_bypasses += skipped_aged
             if r.sched_first_s is None:
                 r.sched_first_s = self.now
+            if r.admit_wall_s is None:
+                r.admit_wall_s = time.perf_counter()
             n_admit += 1
         return whole, chunks
 
@@ -1044,7 +1053,8 @@ class MorphServeEngine:
         bs = self.pool.block_size
         if whole:
             if self.ec.compute == "real":
-                firsts = self._prefill_real_many(whole)
+                with span("serve.prefill", rid=whole[0].rid, n=len(whole)):
+                    firsts = self._prefill_real_many(whole)
             else:
                 firsts = [self._sim_token(r) for r in whole]
             for r, first in zip(whole, firsts):
@@ -1056,7 +1066,8 @@ class MorphServeEngine:
                 continue                        # preempted after scheduling
             first = None
             if self.ec.compute == "real":
-                first = self._prefill_chunk_real(r, clen)
+                with span("serve.prefill", rid=r.rid):
+                    first = self._prefill_chunk_real(r, clen)
             r.prefill_pos += clen
             r.prefill_chunks += 1
             r.note_prefill_levels(pos0, pos0 + clen, lvl, bs)
@@ -1088,7 +1099,8 @@ class MorphServeEngine:
             self.pool.k, self.pool.v, jnp.array(table),
             self.pool.kv_quant_bundle())
         if pos0 + clen == r.prompt_len:
-            return int(jnp.argmax(logits[clen - 1]))
+            with span("serve.readback"):
+                return int(jnp.argmax(logits[clen - 1]))
         return None
 
     def _prefill_real_many(self, admitted: List[Request]) -> List[int]:
@@ -1113,7 +1125,8 @@ class MorphServeEngine:
         last, self.pool.k, self.pool.v = self.exec.prefill_batch(
             self.actuator.layer_list(), jnp.array(toks),
             self.pool.k, self.pool.v, jnp.array(tables), jnp.array(lens))
-        toks_out = np.asarray(jnp.argmax(last, axis=-1))
+        with span("serve.readback"):
+            toks_out = np.asarray(jnp.argmax(last, axis=-1))
         return [int(toks_out[i]) for i in range(len(admitted))]
 
     def _prefill_real(self, r: Request) -> int:
@@ -1134,7 +1147,8 @@ class MorphServeEngine:
             self.exec.prefill(self.actuator.layer_list(), jnp.array(toks),
                               self.pool.k, self.pool.v, ids,
                               self.ssm_conv, self.ssm_ssm, r.slot)
-        return int(jnp.argmax(logits[r.prompt_len - 1]))
+        with span("serve.readback"):
+            return int(jnp.argmax(logits[r.prompt_len - 1]))
 
     # ------------------------------------------------------------------
     def _ensure_decode_blocks(self) -> List[Request]:
@@ -1272,7 +1286,8 @@ class MorphServeEngine:
                              jnp.array(pos), self.pool.k, self.pool.v,
                              jnp.array(tables), self.ssm_conv, self.ssm_ssm,
                              self.pool.kv_quant_bundle())
-        toks = np.asarray(jnp.argmax(logits, axis=-1))
+        with span("serve.readback"):
+            toks = np.asarray(jnp.argmax(logits, axis=-1))
         for r in run:
             r.generated.append(int(toks[r.slot]))
 
@@ -1523,8 +1538,9 @@ class MorphServeEngine:
             return
         level_changed = self.actuator.poll(self.now)
         if level_changed:
-            self.controller.commit(self.actuator.level)
-            self._commit_landed_weights(self.actuator.weight_bytes())
+            with span("relief.swap"):
+                self.controller.commit(self.actuator.level)
+                self._commit_landed_weights(self.actuator.weight_bytes())
         sig = self.monitor.signals()
         sig["time_s"] = self.now
         if self.ec.max_tokens_per_step > 0:
@@ -1576,11 +1592,14 @@ class MorphServeEngine:
         # grow_kv below converts the ledger relief into pool capacity the
         # same tick), or restore a paced batch to fp on the calm path
         if cmd.quantize_kv:
-            self._quantize_cold()
+            with span("relief.kv_quantize"):
+                self._quantize_cold()
         if cmd.dequantize_kv and self.actuator.level == 0:
-            self._dequantize_restore()
+            with span("relief.kv_quantize"):
+                self._dequantize_restore()
         if cmd.target_level > self.actuator.level and not self.actuator.busy:
-            self.actuator.issue(cmd.target_level, self.now)
+            with span("relief.swap"):
+                self.actuator.issue(cmd.target_level, self.now)
         if cmd.grow_kv:
             # grow only against *committed* (already-freed) weight bytes —
             # and never into the space an in-flight restore (a swap toward
@@ -1592,9 +1611,10 @@ class MorphServeEngine:
             dec = self.resizer.grow(weight_bytes=wb_grow,
                                     live_blocks=self._live_kv_blocks())
             if dec is not None:
-                self.ledger.resize_kv(dec.new_blocks)
-                self.pool.resize(dec.new_blocks + 1)
-                self.resize_log.append((self.now, dec.new_blocks))
+                with span("relief.kv_resize"):
+                    self.ledger.resize_kv(dec.new_blocks)
+                    self.pool.resize(dec.new_blocks + 1)
+                    self.resize_log.append((self.now, dec.new_blocks))
         if cmd.target_level < self.actuator.level and not self.actuator.busy:
             # shrink pool first if the restored weights wouldn't fit; a
             # busy tail yields a partial shrink and the restore retries
@@ -1606,20 +1626,23 @@ class MorphServeEngine:
                     weight_bytes=wb_restored,
                     live_blocks=self._live_kv_blocks())
                 if dec is not None:
-                    applied = self._shrink_pool(dec.new_blocks)
-                    if applied is not None:
-                        self.ledger.resize_kv(applied)
-                        self.resize_log.append((self.now, applied))
+                    with span("relief.kv_resize"):
+                        applied = self._shrink_pool(dec.new_blocks)
+                        if applied is not None:
+                            self.ledger.resize_kv(applied)
+                            self.resize_log.append((self.now, applied))
             if self.resizer.fits_restore(weight_bytes_restored=wb_restored):
-                self.actuator.issue(cmd.target_level, self.now)
+                with span("relief.swap"):
+                    self.actuator.issue(cmd.target_level, self.now)
         elif cmd.shrink_kv and self.actuator.level == 0:
             dec = self.resizer.shrink(weight_bytes=self.ledger.weight_bytes,
                                       live_blocks=self._live_kv_blocks())
             if dec is not None:
-                applied = self._shrink_pool(dec.new_blocks)
-                if applied is not None:
-                    self.ledger.resize_kv(applied)
-                    self.resize_log.append((self.now, applied))
+                with span("relief.kv_resize"):
+                    applied = self._shrink_pool(dec.new_blocks)
+                    if applied is not None:
+                        self.ledger.resize_kv(applied)
+                        self.resize_log.append((self.now, applied))
 
     # ------------------------------------------------------------------
     # step-loop invariant watchdog (graceful degradation, not crashes)
@@ -1763,31 +1786,52 @@ class MorphServeEngine:
         the remainder prompt chunks — one mixed batch per step, so decode
         throughput is never head-of-line blocked behind a long prompt and
         queued requests' TTFT follows the chunk budget, not the longest
-        prompt in front of them."""
-        dec0 = [(r, len(r.generated), r.preemptions) for r in self.decoding]
-        whole, chunks = self._schedule_prefill()
-        emitted = self._exec_prefill(whole, chunks)
-        pf_tokens = sum(r.prompt_len for r in whole) + \
-            sum(c for _, _, c in chunks)
-        # causal (q, kv) score pairs + paged context the chunks re-read
-        pf_pairs = sum(r.prompt_len ** 2 / 2 for r in whole) + \
-            sum(c * p0 + c * c / 2 for _, p0, c in chunks)
-        pf_kv = sum(p0 + c for _, p0, c in chunks)
-        dec = self.decoding
-        stalled_rids: set = set()
-        if dec:
-            stalled = self._ensure_decode_blocks()
-            stalled_rids = {r.rid for r in stalled}
-            # a request stalled on a transient allocation fault has no KV
-            # slot for its next token: it skips this decode and retries
-            # next step (bounded by alloc_retry_limit before preemption)
-            dec = [r for r in self.decoding if r.rid not in stalled_rids]
-        if dec:
-            if self.ec.compute == "real":
-                self._decode_real(dec)
-            else:
-                for r in dec:
-                    r.generated.append(self._sim_token(r))
+        prompt in front of them. Each phase runs in a host span
+        (``engine/spans.py``) whose totals ride on the step's Telemetry."""
+        with span("serve.step") as rec:
+            dec0 = [(r, len(r.generated), r.preemptions)
+                    for r in self.decoding]
+            with span("serve.schedule"):
+                whole, chunks = self._schedule_prefill()
+            emitted = self._exec_prefill(whole, chunks)
+            pf_tokens = sum(r.prompt_len for r in whole) + \
+                sum(c for _, _, c in chunks)
+            # causal (q, kv) score pairs + paged context the chunks re-read
+            pf_pairs = sum(r.prompt_len ** 2 / 2 for r in whole) + \
+                sum(c * p0 + c * c / 2 for _, p0, c in chunks)
+            pf_kv = sum(p0 + c for _, p0, c in chunks)
+            dec = self.decoding
+            stalled_rids: set = set()
+            if dec:
+                with span("serve.decode_blocks"):
+                    stalled = self._ensure_decode_blocks()
+                stalled_rids = {r.rid for r in stalled}
+                # a request stalled on a transient allocation fault has no KV
+                # slot for its next token: it skips this decode and retries
+                # next step (bounded by alloc_retry_limit before preemption)
+                dec = [r for r in self.decoding if r.rid not in stalled_rids]
+            if dec:
+                if self.ec.compute == "real":
+                    with span("serve.decode"):
+                        self._decode_real(dec)
+                else:
+                    for r in dec:
+                        r.generated.append(self._sim_token(r))
+            with span("serve.account"):
+                dt = self._account(rec, dec0, dec, emitted, stalled_rids,
+                                   (pf_tokens, pf_pairs, pf_kv))
+            with span("serve.morph"):
+                self._morph_tick()
+        return dt
+
+    def _account(self, rec, dec0, dec, emitted, stalled_rids,
+                 prefill) -> float:
+        """The step's bookkeeping once its tokens are on the host: the
+        modelled step time from its decodes and ``prefill`` (tokens, score
+        pairs, paged context re-read), token stamps, finishes, the step's
+        Telemetry (``rec``: its host spans) and the watchdog. Returns the
+        modelled step time."""
+        pf_tokens, pf_pairs, pf_kv = prefill
         lvl = self.actuator.level
         if dec or pf_tokens:
             total_ctx = sum(r.context_len for r in dec)
@@ -1837,9 +1881,6 @@ class MorphServeEngine:
         urgent = max(((self.now - r.arrival_s) * self._slo(r).pressure_weight
                       for r in self.queue if r.arrival_s <= self.now),
                      default=0.0)
-        backlog = sum(r.prefill_remaining for r in self.running
-                      if r.state == RState.PREFILLING) + \
-            sum(r.prompt_len for r in self.queue if r.arrival_s <= self.now)
         self.monitor.observe(Telemetry(
             time_s=self.now,
             kv_used_blocks=self.pool.alloc.n_used,
@@ -1851,18 +1892,15 @@ class MorphServeEngine:
             step_time_s=dt,
             decode_tokens=len(dec),
             prefill_tokens=pf_tokens,
-            prefill_backlog_tokens=backlog,
             chunk_budget=self.chunk_budget,
-            prefix_cached_blocks=(self.prefix_cache.resident_blocks
-                                  if self.prefix_cache is not None else 0),
             urgent_wait_s=urgent,
             kv_quant_blocks=len(self.pool.qbits),
-            kv_bytes_relieved=self.ledger.bytes_relieved))
+            kv_bytes_relieved=self.ledger.bytes_relieved,
+            spans=rec))
         self._step_idx += 1
         if self.ec.watchdog_interval > 0 \
                 and self._step_idx % self.ec.watchdog_interval == 0:
             self._check_invariants()
-        self._morph_tick()
         return dt
 
     def run_trace(self, trace: List[TraceRequest], *,

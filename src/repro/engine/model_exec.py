@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.engine.kv_cache import write_blocks
+from repro.engine.spans import span
 from repro.kernels import dispatch, ops
 from repro.models import layers as L
 from repro.models import lm
@@ -462,21 +463,24 @@ class ModelExec:
     def decode(self, layer_list, tokens, pos, pool_k, pool_v, tables,
                ssm_conv, ssm_ssm, kvq=None):
         lp = self._decode_params(layer_list)
-        return self._decode_jit(self.misc, lp, tokens, pos,
-                                pool_k, pool_v, tables, ssm_conv, ssm_ssm,
-                                kvq)
+        with span("exec.decode"):
+            return self._decode_jit(self.misc, lp, tokens, pos,
+                                    pool_k, pool_v, tables, ssm_conv,
+                                    ssm_ssm, kvq)
 
     def prefill(self, layer_list, tokens, pool_k, pool_v, block_ids,
                 ssm_conv, ssm_ssm, slot):
         lp = tuple(p for _, p in layer_list)
-        return self._prefill_jit(self.misc, lp, tokens,
-                                 pool_k, pool_v, block_ids, ssm_conv,
-                                 ssm_ssm, slot)
+        with span("exec.prefill"):
+            return self._prefill_jit(self.misc, lp, tokens,
+                                     pool_k, pool_v, block_ids, ssm_conv,
+                                     ssm_ssm, slot)
 
     def prefill_batch(self, layer_list, tokens, pool_k, pool_v, tables, lens):
         lp = tuple(p for _, p in layer_list)
-        return self._prefill_batch_jit(self.misc, lp, tokens,
-                                       pool_k, pool_v, tables, lens)
+        with span("exec.prefill_batch"):
+            return self._prefill_batch_jit(self.misc, lp, tokens,
+                                           pool_k, pool_v, tables, lens)
 
     def prefill_chunk(self, layer_list, tokens, pos0, pool_k, pool_v, table,
                       kvq=None):
@@ -487,5 +491,6 @@ class ModelExec:
             lp = self._decode_params(layer_list)
         else:
             lp = tuple(p for _, p in layer_list)
-        return self._prefill_chunk_jit(self.misc, lp, tokens, pos0,
-                                       pool_k, pool_v, table, kvq)
+        with span("exec.prefill_chunk"):
+            return self._prefill_chunk_jit(self.misc, lp, tokens, pos0,
+                                           pool_k, pool_v, table, kvq)

@@ -111,6 +111,11 @@ class Request:
     # blocks) — per-class queue-wait accounting; preserved across
     # preemption (unlike prefill_pos)
     sched_first_s: Optional[float] = None
+    # the same two moments on the wall clock (time.perf_counter): the
+    # engine's submit, and the first time the scheduler gave the request a
+    # slot (kept across preemption)
+    submit_wall_s: Optional[float] = None
+    admit_wall_s: Optional[float] = None
     # quantized-KV tolerance tag: set when this request adopted a prefix
     # cache entry whose KV is (or was re-)quantized below fp precision,
     # e.g. "int8" — surfaces the approximation in terminal records
