@@ -86,7 +86,8 @@ def _paged_gqa_decode(p, cfg, x, pool_k, pool_v, li, tables, pos, spec,
     the caller-truncated width of ``tables``, not max_blocks_per_seq.
     ``spec`` is the layer's static :class:`~repro.kernels.ops.AttentionSpec`;
     ``kvq`` the layer's quantized-KV sidecar slice (or None when the pool
-    holds no quantized blocks this step).
+    holds no quantized blocks this step). The walk takes the stacked pool
+    and ``layer=li``: a ``pool_k[li]`` operand would be copied out whole.
     """
     slots = x.shape[0]
     bs = pool_k.shape[2]
@@ -95,8 +96,8 @@ def _paged_gqa_decode(p, cfg, x, pool_k, pool_v, li, tables, pos, spec,
     pool_k, pool_v = _append_kv(pool_k, pool_v, li, k[:, 0], v[:, 0],
                                 blk_idx, pos % bs)
     out = ops.paged_decode_attention(
-        q[:, 0], k[:, 0], v[:, 0], pool_k[li], pool_v[li], tables, pos, spec,
-        kv_quant=kvq)
+        q[:, 0], k[:, 0], v[:, 0], pool_k, pool_v, tables, pos, spec,
+        layer=li, kv_quant=kvq)
     y = qlinear.matmul(out.reshape(slots, 1, -1), p["wo"], bias=p.get("bo"))
     return y, pool_k, pool_v
 
@@ -289,9 +290,9 @@ def _chunk_gqa_attention(p, cfg, x, positions, pool_k, pool_v, li, tables,
     q, k, v = L.gqa_project_qkv(p, cfg, x, positions)
     pool_k = pool_k.at[li, blk, off].set(k[0].astype(pool_k.dtype))
     pool_v = pool_v.at[li, blk, off].set(v[0].astype(pool_v.dtype))
-    out = ops.paged_prefill_attention(q, pool_k[li], pool_v[li],
-                                      tables[None], pos0, spec,
-                                      k_new=k, v_new=v, kv_quant=kvq)
+    out = ops.paged_prefill_attention(q, pool_k, pool_v, tables[None], pos0,
+                                      spec, layer=li, k_new=k, v_new=v,
+                                      kv_quant=kvq)
     y = qlinear.matmul(out.reshape(B, Cp, -1), p["wo"], bias=p.get("bo"))
     return y, pool_k, pool_v
 
@@ -318,7 +319,7 @@ def _chunk_mla_attention(p, cfg, x, positions, pool_k, li, tables, blk, off,
                            p["wk_abs"])
         q_lat = jnp.concatenate([q_abs, q_rope.astype(jnp.float32)], -1)
         ctx_lat = ops.paged_prefill_attention(
-            q_lat, pool_k[li], pool_k[li], tables[None], pos0, spec,
+            q_lat, pool_k, pool_k, tables[None], pos0, spec, layer=li,
             k_new=latent_new[None, :, None, :],
             v_new=latent_new[None, :, None, :])
         out = jnp.einsum("bshr,rhd->bshd", ctx_lat.astype(jnp.float32),

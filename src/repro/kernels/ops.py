@@ -128,6 +128,14 @@ def wna16_matmul(x2, qt, *, bias=None):
 # ---------------------------------------------------------------------------
 # paged attention (decode + chunked prefill), AttentionSpec-driven
 # ---------------------------------------------------------------------------
+def _layer_of(k_pool, v_pool, layer):
+    """The xla path's pools for ``layer`` of a stacked pool (XLA fuses the
+    index into the gather); with no ``layer`` the pools are one layer's."""
+    if layer is None:
+        return k_pool, v_pool
+    return k_pool[layer], v_pool[layer]
+
+
 def paged_attention(q, k_pool, v_pool, block_tables, context_lens,
                     spec: AttentionSpec = None, *,
                     kv_quant=None, window: int = 0, softcap: float = 0.0):
@@ -152,7 +160,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens,
 
 
 def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, block_tables,
-                           pos, spec: AttentionSpec = None, *,
+                           pos, spec: AttentionSpec = None, *, layer=None,
                            kv_quant=None, window: int = 0,
                            softcap: float = 0.0):
     """Decode attention over pool KV + the current token (B, KVH, Dh).
@@ -162,6 +170,7 @@ def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, block_tables,
     Pallas kernel only reads positions < pos and takes the new token as a
     VMEM operand). ``block_tables`` may be truncated to any width covering
     ``pos // block_size``; cost scales with that width on the gather path.
+    ``layer`` picks the layer of a stacked pool: the walk reads it in place.
 
     Dispatch: ``pallas``/``pallas_interpret`` run the fused block-walk
     kernel; ``xla`` the bucketed jnp gather; ``auto`` picks by backend.
@@ -169,19 +178,21 @@ def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, block_tables,
     spec = _spec_of(spec, window, softcap)
     mode = dispatch.resolve()
     if mode == "xla":
+        k_pool, v_pool = _layer_of(k_pool, v_pool, layer)
         return pa.paged_gather_attention(q, k_pool, v_pool, block_tables,
                                          pos, window=spec.window,
                                          softcap=spec.softcap,
                                          scale=spec.scale, kv_quant=kv_quant)
     return pa.paged_attention_fused(q, k_new, v_new, k_pool, v_pool,
-                                    block_tables, pos, window=spec.window,
+                                    block_tables, pos, layer=layer,
+                                    window=spec.window,
                                     softcap=spec.softcap, scale=spec.scale,
                                     interpret=(mode == "pallas_interpret"),
                                     kv_quant=kv_quant)
 
 
 def paged_prefill_attention(q, k_pool, v_pool, block_tables, pos0,
-                            spec: AttentionSpec = None, *,
+                            spec: AttentionSpec = None, *, layer=None,
                             k_new=None, v_new=None, kv_quant=None,
                             window: int = 0, softcap: float = 0.0):
     """Chunked-prefill attention: C queries at positions ``pos0 + i`` over
@@ -200,11 +211,13 @@ def paged_prefill_attention(q, k_pool, v_pool, block_tables, pos0,
     caller-bucketed table width, not ``max_blocks_per_seq``.
 
     MLA latent pools go through ``spec.latent_dv``/``spec.scale`` with the
-    absorbed query (see ``model_exec._chunk_mla_attention``).
+    absorbed query (see ``model_exec._chunk_mla_attention``). ``layer``
+    picks the layer of a stacked pool, as in ``paged_decode_attention``.
     """
     spec = _spec_of(spec, window, softcap)
     mode = dispatch.resolve()
     if mode == "xla":
+        k_pool, v_pool = _layer_of(k_pool, v_pool, layer)
         return pa.paged_chunk_gather_attention(
             q, k_pool, v_pool, block_tables, pos0, window=spec.window,
             softcap=spec.softcap, scale=spec.scale, dv=spec.latent_dv,
@@ -212,10 +225,10 @@ def paged_prefill_attention(q, k_pool, v_pool, block_tables, pos0,
     interpret = mode == "pallas_interpret"
     if k_new is not None:
         return pa.paged_chunk_attention_fused(
-            q, k_new, v_new, k_pool, v_pool, block_tables, pos0,
+            q, k_new, v_new, k_pool, v_pool, block_tables, pos0, layer=layer,
             window=spec.window, softcap=spec.softcap, scale=spec.scale,
             dv=spec.latent_dv, interpret=interpret, kv_quant=kv_quant)
     return pa.paged_chunk_attention(
-        q, k_pool, v_pool, block_tables, pos0, window=spec.window,
-        softcap=spec.softcap, scale=spec.scale, dv=spec.latent_dv,
-        interpret=interpret, kv_quant=kv_quant)
+        q, k_pool, v_pool, block_tables, pos0, layer=layer,
+        window=spec.window, softcap=spec.softcap, scale=spec.scale,
+        dv=spec.latent_dv, interpret=interpret, kv_quant=kv_quant)

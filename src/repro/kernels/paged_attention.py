@@ -17,6 +17,12 @@ accepts for any head count; a per-head ``(1, block_size, 1, Dh)`` block puts
 a size-1 block on the second-minor axis and is refused by the v5e compiler.
 For a bf16 pool with KVH=2, XLA tiles the last two dims ``T(2,128)``, so
 the layout costs no padding in HBM and reaches the kernel without a copy.
+The walks read the engine's stacked pool ``(L, num_blocks, block_size, KVH,
+Dh)`` in place: the layer index is a third scalar-prefetch operand, and the
+index_map picks ``(layer, tables[b, nb])``. A Pallas call needs each operand
+as a buffer of its own, so a ``pool[layer]`` operand made XLA copy the whole
+layer slice before every call. A 4-D pool is read as ``pool[None]`` at
+layer 0 (a bitcast).
 GQA: the G = H/KVH query heads of a kv head are processed together as the
 (G, Dh) q block.
 
@@ -45,7 +51,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import dispatch
 
 
-def _paged_attn_kernel(tables_ref, lens_ref, *refs,
+def _paged_attn_kernel(tables_ref, lens_ref, layer_ref, *refs,
                        block_size: int, max_nb: int, kv_heads: int,
                        scale: float, window: int, softcap: float,
                        fused_new: bool, kv_quant: bool = False):
@@ -131,9 +137,9 @@ def _paged_attn_kernel(tables_ref, lens_ref, *refs,
 
 
 def _split_refs(refs, kv_quant):
-    """Name a kernel's refs after (tables, lens/pos0): with ``kv_quant`` the
-    sidecar adds the two int8 mirrors and the scale/zero rows after the
-    fused-new operands; without it those three are None."""
+    """Name a kernel's refs after (tables, lens/pos0, layer): with
+    ``kv_quant`` the sidecar adds the two int8 mirrors and the scale/zero
+    rows after the fused-new operands; without it those three are None."""
     if kv_quant:
         q, k, v, kn, vn, qk, qv, qp, *rest = refs
     else:
@@ -143,9 +149,20 @@ def _split_refs(refs, kv_quant):
 
 
 def _pool_spec(bs, kvh, dh):
-    """One whole pool block, every kv head: ``tables[b, nb]`` picks it."""
-    return pl.BlockSpec((1, bs, kvh, dh),
-                        lambda b, nb, tables, *_: (tables[b, nb], 0, 0, 0))
+    """One whole block of the stacked pool, every kv head: ``tables[b, nb]``
+    picks it in layer ``layer[0]``; the kernel sees ``(1, bs, KVH, Dh)``."""
+    return pl.BlockSpec(
+        (None, 1, bs, kvh, dh),
+        lambda b, nb, tables, lens, layer: (layer[0], tables[b, nb], 0, 0, 0))
+
+
+def _stacked(k_pool, v_pool, layer):
+    """(k_pool, v_pool, layer as an int32 (1,) array): a 4-D pool becomes
+    ``pool[None]`` at layer 0."""
+    if k_pool.ndim == 4:
+        assert layer is None or layer == 0, layer
+        k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
+    return k_pool, v_pool, jnp.asarray(layer, jnp.int32).reshape(1)
 
 
 def _row_spec(shape):
@@ -157,20 +174,24 @@ def _row_spec(shape):
 def _walk_call(kernel, *, grid, prefetch, in_specs, operands, kv_quant,
                out_shape, scratch_shapes, interpret, name,
                vmem_limit_bytes=None):
-    """The block walk's ``pallas_call``: tables and lens/pos0 are
-    scalar-prefetched. The quantized-KV sidecar, when given, adds its int8
-    pool mirrors as two more walked inputs and each batch row's
+    """The block walk's ``pallas_call``: tables, lens/pos0 and the layer
+    are scalar-prefetched. The quantized-KV sidecar, when given, adds one
+    layer's int8 pool mirrors as two more walked inputs (stacked, the s8
+    mirrors reach the kernel only through a relayout copy of all layers at
+    once; one layer's slice is relaid out alone) and each batch row's
     (k_scale, v_scale, k_zero, v_zero) for every table entry as a
     ``(4, max_nb)`` SMEM row block, gathered through the tables outside the
     kernel: a (1, 1) VMEM block per pool block is refused by the TPU tiling
     rule, and a pool-wide scalar-prefetch row outgrows the 1 MiB of SMEM
     at 65536 blocks, where this row grows with the table only."""
-    bs, kvh, dh = operands[1].shape[1:]
+    bs, kvh, dh = operands[1].shape[2:]
     if kv_quant is not None:
         qk, qv, ks, vs, kz, vz = kv_quant
         tables = prefetch[0]
         rows = jnp.stack([ks, vs, kz, vz]).astype(jnp.float32)[:, tables]
-        in_specs = in_specs + [_pool_spec(bs, kvh, dh)] * 2 + [
+        mirror = pl.BlockSpec((1, bs, kvh, dh),
+                              lambda b, nb, t, *_: (t[b, nb], 0, 0, 0))
+        in_specs = in_specs + [mirror] * 2 + [
             pl.BlockSpec((1, 4, tables.shape[1]), lambda b, nb, *_: (b, 0, 0),
                          memory_space=pltpu.SMEM)]
         operands = list(operands) + [qk, qv, rows.transpose(1, 0, 2)]
@@ -189,10 +210,10 @@ def _walk_call(kernel, *, grid, prefetch, in_specs, operands, kv_quant,
 
 
 def _paged_call(qg, k_pool, v_pool, k_new, v_new, block_tables, context_lens,
-                *, window, softcap, fused_new, interpret, scale=None,
+                layer, *, window, softcap, fused_new, interpret, scale=None,
                 kv_quant=None):
     B, KVH, G, Dh = qg.shape
-    bs = k_pool.shape[1]
+    bs = k_pool.shape[2]
     max_nb = block_tables.shape[1]
     scale = Dh ** -0.5 if scale is None else scale
 
@@ -200,7 +221,7 @@ def _paged_call(qg, k_pool, v_pool, k_new, v_new, block_tables, context_lens,
         functools.partial(_paged_attn_kernel, block_size=bs, max_nb=max_nb,
                           kv_heads=KVH, scale=scale, window=window,
                           softcap=softcap, fused_new=fused_new),
-        grid=(B, max_nb), prefetch=(block_tables, context_lens),
+        grid=(B, max_nb), prefetch=(block_tables, context_lens, layer),
         in_specs=[_row_spec((KVH, G, Dh)), _pool_spec(bs, KVH, Dh),
                   _pool_spec(bs, KVH, Dh), _row_spec((KVH, 1, Dh)),
                   _row_spec((KVH, 1, Dh))],
@@ -213,10 +234,11 @@ def _paged_call(qg, k_pool, v_pool, k_new, v_new, block_tables, context_lens,
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
-                    window: int = 0, softcap: float = 0.0,
+                    layer=None, window: int = 0, softcap: float = 0.0,
                     scale: float = None, interpret: bool = None,
                     kv_quant=None):
-    """q: (B, H, Dh); pools: (num_blocks, bs, KVH, Dh);
+    """q: (B, H, Dh); pools: the stacked (L, num_blocks, bs, KVH, Dh), read
+    at ``layer``, or one layer's (num_blocks, bs, KVH, Dh);
     block_tables: (B, max_nb) int32; context_lens: (B,) int32 → (B, H, Dh).
 
     All ``context_lens[b]`` tokens live in the pool; the query is the token at
@@ -224,45 +246,51 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
     window masking anchored at that position; ``softcap`` > 0 tanh-caps the
     logits. Unused table entries may hold any valid block id (length-masked).
 
-    ``kv_quant`` is the optional quantized-KV sidecar bundle
-    ``(qk, qv, k_scale, v_scale, k_zero, v_zero)`` — int8 pool mirrors plus
-    per-block f32 scale/zero vectors — enabling in-kernel dequant of
-    quantized blocks during the walk (fp blocks carry zeroed params).
+    ``layer`` picks the layer of a stacked pool (a Python int or an int32
+    scalar); a 4-D pool is layer 0.
+
+    ``kv_quant`` is the optional quantized-KV sidecar bundle of that one
+    layer, ``(qk, qv, k_scale, v_scale, k_zero, v_zero)`` — int8 pool
+    mirrors plus per-block f32 scale/zero vectors — enabling in-kernel
+    dequant of quantized blocks during the walk (fp blocks carry zeroed
+    params).
 
     ``interpret=None`` resolves through :func:`dispatch.kernel_interpret`
     (compiled under ``pallas``, interpreted under ``pallas_interpret``).
     """
+    k_pool, v_pool, layer = _stacked(k_pool, v_pool, layer)
     B, H, Dh = q.shape
-    KVH = k_pool.shape[2]
+    KVH = k_pool.shape[3]
     G = H // KVH
     qg = q.reshape(B, KVH, G, Dh)
     zero = jnp.zeros((B, KVH, 1, Dh), q.dtype)
     out = _paged_call(qg, k_pool, v_pool, zero, zero, block_tables,
-                      context_lens, window=window, softcap=softcap,
+                      context_lens, layer, window=window, softcap=softcap,
                       scale=scale, fused_new=False, interpret=interpret,
                       kv_quant=kv_quant)
     return out.reshape(B, H, Dh)
 
 
 def paged_attention_fused(q, k_new, v_new, k_pool, v_pool, block_tables,
-                          pos, *, window: int = 0, softcap: float = 0.0,
-                          scale: float = None, interpret: bool = None,
-                          kv_quant=None):
+                          pos, *, layer=None, window: int = 0,
+                          softcap: float = 0.0, scale: float = None,
+                          interpret: bool = None, kv_quant=None):
     """Fused decode step: ``pos[b]`` tokens are in the pool and the current
     token's (k_new, v_new) — shape (B, KVH, Dh) — enters the softmax as an
     operand at position ``pos[b]`` without a pool read. Returns (B, H, Dh).
 
     The caller owns the pool scatter (the append itself); this kernel only
     *reads* positions < pos, so append and attention have no data dependence.
-    ``kv_quant``/``interpret``: see :func:`paged_attention`.
+    ``layer``/``kv_quant``/``interpret``: see :func:`paged_attention`.
     """
+    k_pool, v_pool, layer = _stacked(k_pool, v_pool, layer)
     B, H, Dh = q.shape
-    KVH = k_pool.shape[2]
+    KVH = k_pool.shape[3]
     G = H // KVH
     qg = q.reshape(B, KVH, G, Dh)
     kn = k_new.reshape(B, KVH, 1, Dh).astype(k_pool.dtype)
     vn = v_new.reshape(B, KVH, 1, Dh).astype(v_pool.dtype)
-    out = _paged_call(qg, k_pool, v_pool, kn, vn, block_tables, pos,
+    out = _paged_call(qg, k_pool, v_pool, kn, vn, block_tables, pos, layer,
                       window=window, softcap=softcap, scale=scale,
                       fused_new=True, interpret=interpret, kv_quant=kv_quant)
     return out.reshape(B, H, Dh)
@@ -271,7 +299,7 @@ def paged_attention_fused(q, k_new, v_new, k_pool, v_pool, block_tables,
 # ---------------------------------------------------------------------------
 # chunked-prefill attention: a chunk of queries over partially-paged context
 # ---------------------------------------------------------------------------
-def _chunk_attn_kernel(tables_ref, pos0_ref, *refs,
+def _chunk_attn_kernel(tables_ref, pos0_ref, layer_ref, *refs,
                        block_size: int, max_nb: int, kv_heads: int,
                        chunk: int, groups: int, scale: float, window: int,
                        softcap: float, dv: int, fused_new: bool,
@@ -393,11 +421,11 @@ def _chunk_attn_kernel(tables_ref, pos0_ref, *refs,
                            jnp.maximum(l_scr[h], 1e-30)).astype(o_ref.dtype)
 
 
-def _chunk_call(qg, k_pool, v_pool, kn, vn, block_tables, pos0, *, chunk,
-                groups, scale, window, softcap, dv, fused_new, interpret,
-                kv_quant=None):
+def _chunk_call(qg, k_pool, v_pool, kn, vn, block_tables, pos0, layer, *,
+                chunk, groups, scale, window, softcap, dv, fused_new,
+                interpret, kv_quant=None):
     B, KVH, CG, Dh = qg.shape
-    bs = k_pool.shape[1]
+    bs = k_pool.shape[2]
     max_nb = block_tables.shape[1]
 
     return _walk_call(
@@ -405,7 +433,7 @@ def _chunk_call(qg, k_pool, v_pool, kn, vn, block_tables, pos0, *, chunk,
                           kv_heads=KVH, chunk=chunk, groups=groups,
                           scale=scale, window=window, softcap=softcap, dv=dv,
                           fused_new=fused_new),
-        grid=(B, max_nb), prefetch=(block_tables, pos0),
+        grid=(B, max_nb), prefetch=(block_tables, pos0, layer),
         in_specs=[_row_spec((KVH, CG, Dh)), _pool_spec(bs, KVH, Dh),
                   _pool_spec(bs, KVH, Dh), _row_spec((KVH,) + kn.shape[2:]),
                   _row_spec((KVH,) + vn.shape[2:])],
@@ -419,9 +447,10 @@ def _chunk_call(qg, k_pool, v_pool, kn, vn, block_tables, pos0, *, chunk,
 
 
 def _chunk_io(q, k_pool):
-    """(B, C, H, Dh) queries → (B, KVH, C*G, Dh) kernel layout + dims."""
+    """(B, C, H, Dh) queries → (B, KVH, C*G, Dh) kernel layout + dims, for
+    a stacked pool."""
     B, C, H, Dh = q.shape
-    KVH = k_pool.shape[2]
+    KVH = k_pool.shape[3]
     G = H // KVH
     qg = q.reshape(B, C, KVH, G, Dh).transpose(0, 2, 1, 3, 4)
     return qg.reshape(B, KVH, C * G, Dh), (B, C, H, KVH, G, Dh)
@@ -432,7 +461,7 @@ def _pos0_vec(pos0, B):
 
 
 def paged_chunk_attention(q, k_pool, v_pool, block_tables, pos0, *,
-                          window: int = 0, softcap: float = 0.0,
+                          layer=None, window: int = 0, softcap: float = 0.0,
                           scale: float = None, dv: int = None,
                           interpret: bool = None, kv_quant=None):
     """Pool-read chunk block walk: q (B, C, H, Dh), chunk KV already
@@ -443,25 +472,26 @@ def paged_chunk_attention(q, k_pool, v_pool, block_tables, pos0, *,
 
     ``scale`` overrides the default ``Dh ** -0.5``; ``dv`` < Dh enables the
     MLA latent mode (values = first ``dv`` lanes of the latent pool).
-    ``kv_quant``/``interpret``: see :func:`paged_attention`.
+    ``layer``/``kv_quant``/``interpret``: see :func:`paged_attention`.
     """
+    k_pool, v_pool, layer = _stacked(k_pool, v_pool, layer)
     qg, (B, C, H, KVH, G, Dh) = _chunk_io(q, k_pool)
     dv = dv or Dh
     scale = Dh ** -0.5 if scale is None else scale
     zero = jnp.zeros((B, KVH, 1, Dh), k_pool.dtype)
     out = _chunk_call(qg, k_pool, v_pool, zero, zero, block_tables,
-                      _pos0_vec(pos0, B), chunk=C, groups=G, scale=scale,
-                      window=window, softcap=softcap, dv=dv, fused_new=False,
-                      interpret=interpret, kv_quant=kv_quant)
+                      _pos0_vec(pos0, B), layer, chunk=C, groups=G,
+                      scale=scale, window=window, softcap=softcap, dv=dv,
+                      fused_new=False, interpret=interpret, kv_quant=kv_quant)
     return out.reshape(B, KVH, C, G, dv).transpose(0, 2, 1, 3, 4).reshape(
         B, C, H, dv)
 
 
 def paged_chunk_attention_fused(q, k_new, v_new, k_pool, v_pool,
-                                block_tables, pos0, *, window: int = 0,
-                                softcap: float = 0.0, scale: float = None,
-                                dv: int = None, interpret: bool = None,
-                                kv_quant=None):
+                                block_tables, pos0, *, layer=None,
+                                window: int = 0, softcap: float = 0.0,
+                                scale: float = None, dv: int = None,
+                                interpret: bool = None, kv_quant=None):
     """Multi-token batched-append chunk walk: the block walk covers only the
     already-paged context (< ``pos0``) and the chunk's own (k_new, v_new) —
     shape (B, C, KVH, Dh) — enter the online softmax as VMEM operands under
@@ -469,16 +499,17 @@ def paged_chunk_attention_fused(q, k_new, v_new, k_pool, v_pool,
     kernel's fused single-token append. The caller still owns the pool
     scatter of the chunk's KV (for later chunks/decode); this kernel never
     reads pool positions ``>= pos0``, so scatter and walk are independent.
-    Returns (B, C, H, dv)."""
+    Returns (B, C, H, dv); ``layer``: see :func:`paged_attention`."""
+    k_pool, v_pool, layer = _stacked(k_pool, v_pool, layer)
     qg, (B, C, H, KVH, G, Dh) = _chunk_io(q, k_pool)
     dv = dv or Dh
     scale = Dh ** -0.5 if scale is None else scale
     kn = k_new.transpose(0, 2, 1, 3).astype(k_pool.dtype)   # (B, KVH, C, Dh)
     vn = v_new.transpose(0, 2, 1, 3).astype(v_pool.dtype)
     out = _chunk_call(qg, k_pool, v_pool, kn, vn, block_tables,
-                      _pos0_vec(pos0, B), chunk=C, groups=G, scale=scale,
-                      window=window, softcap=softcap, dv=dv, fused_new=True,
-                      interpret=interpret, kv_quant=kv_quant)
+                      _pos0_vec(pos0, B), layer, chunk=C, groups=G,
+                      scale=scale, window=window, softcap=softcap, dv=dv,
+                      fused_new=True, interpret=interpret, kv_quant=kv_quant)
     return out.reshape(B, KVH, C, G, dv).transpose(0, 2, 1, 3, 4).reshape(
         B, C, H, dv)
 

@@ -436,6 +436,89 @@ def test_ops_paged_prefill_attention_dispatch():
                                    rtol=2e-5, atol=2e-5)
 
 
+# ---------------------------------------------------------------------------
+# stacked (L, blocks, bs, KVH, Dh) pools read in place at a layer index
+# ---------------------------------------------------------------------------
+def _stack_at(pool, layer, seed, n_layers=3):
+    """An ``n_layers`` stacked pool holding ``pool`` at ``layer`` and noise
+    in every other layer, so a walk that reads the wrong layer shows."""
+    noise = jax.random.normal(jax.random.PRNGKey(seed),
+                              (n_layers,) + pool.shape, pool.dtype)
+    return noise.at[layer].set(pool)
+
+
+@pytest.mark.parametrize("layer", [1, 2])
+@pytest.mark.parametrize("window,softcap", [(0, 0.0), (7, 25.0)])
+@pytest.mark.parametrize("walk", ["decode", "chunk"])
+def test_stacked_pool_walk_reads_layer_in_place(walk, window, softcap,
+                                                layer):
+    """The fused decode and chunk walks over a stacked 3-layer pool at
+    ``layer`` (through ``ops`` under ``pallas_interpret``) equal the 4-D
+    call on ``pool[layer]`` bit for bit, and the ``xla`` gather through
+    ``ops`` at the same layer."""
+    from repro.kernels import paged_attention as pa
+    spec = ops.AttentionSpec(window=window, softcap=softcap)
+    if walk == "decode":
+        B, H, KVH, Dh, bs, maxnb = 2, 8, 2, 64, 16, 4
+        q, kp, vp, tables, pos, kn, vn = _fused_case(layer, B, H, KVH, Dh,
+                                                     bs, maxnb)
+        # the pool holds the appended token (the xla gather's contract)
+        blk = jnp.take_along_axis(tables, (pos // bs)[:, None], axis=1)[:, 0]
+        kp = kp.at[blk, pos % bs].set(kn)
+        vp = vp.at[blk, pos % bs].set(vn)
+        kps, vps = _stack_at(kp, layer, 100), _stack_at(vp, layer, 101)
+        want = pa.paged_attention_fused(q, kn, vn, kp, vp, tables, pos,
+                                        window=window, softcap=softcap,
+                                        interpret=True)
+
+        def call(kpool, vpool):
+            return ops.paged_decode_attention(q, kn, vn, kpool, vpool, tables,
+                                              pos, spec, layer=layer)
+    else:
+        B, C, H, KVH, Dh, bs, maxnb, pos0 = 2, 8, 8, 2, 64, 16, 4, 13
+        q, kp, vp, tables, kn, vn = _chunk_case(layer, B, C, H, KVH, Dh, bs,
+                                                maxnb, pos0)
+        kps, vps = _stack_at(kp, layer, 100), _stack_at(vp, layer, 101)
+        want = pa.paged_chunk_attention_fused(q, kn, vn, kp, vp, tables,
+                                              pos0, window=window,
+                                              softcap=softcap, interpret=True)
+
+        def call(kpool, vpool):
+            return ops.paged_prefill_attention(q, kpool, vpool, tables, pos0,
+                                               spec, layer=layer, k_new=kn,
+                                               v_new=vn)
+    with quant_kernel_mode("pallas_interpret"):
+        out = call(kps, vps)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+    with quant_kernel_mode("xla"):
+        out_x = call(kps, vps)
+    np.testing.assert_allclose(np.asarray(out_x), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("layer", [1, 2])
+def test_stacked_pool_mla_latent_chunk(layer):
+    """MLA latent mode over a stacked latent pool (passed as both K and V)
+    equals its 4-D call on ``pool[layer]``, for both chunk variants."""
+    from repro.kernels import paged_attention as pa
+    B, C, H, r, rope, bs, maxnb, pos0 = 2, 8, 8, 32, 16, 8, 6, 21
+    kw = dict(scale=48.0 ** -0.5, dv=r, interpret=True)
+    q, kp, _, tables, kn, _ = _chunk_case(3, B, C, H, 1, r + rope, bs, maxnb,
+                                          pos0)
+    kps = _stack_at(kp, layer, 102)
+    pairs = [
+        (pa.paged_chunk_attention(q, kps, kps, tables, pos0, layer=layer,
+                                  **kw),
+         pa.paged_chunk_attention(q, kp, kp, tables, pos0, **kw)),
+        (pa.paged_chunk_attention_fused(q, kn, kn, kps, kps, tables, pos0,
+                                        layer=layer, **kw),
+         pa.paged_chunk_attention_fused(q, kn, kn, kp, kp, tables, pos0,
+                                        **kw)),
+    ]
+    for got, want in pairs:
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_paged_matches_dense_attention():
     """Paged oracle == dense causal attention when the table is contiguous."""
     from repro.models.layers import naive_attention

@@ -12,13 +12,21 @@ The topology is built inside a module fixture: describing it loads the TPU
 library, which one process at a time may hold, so nothing here touches it
 while the module is imported.
 """
+import dataclasses
+import functools
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs.archs import QWEN2_1P5B
+from repro.engine import model_exec as me
+from repro.kernels import dispatch
 from repro.kernels import paged_attention as pa
 from repro.kernels.wna16_gemm import wna16_gemm
+from repro.models import lm
 
 H, KVH, DH, BS = 12, 2, 128, 16          # Qwen2-1.5B attention
 D_MODEL, D_FF, GROUP = 1536, 8960, 128   # Qwen2-1.5B MLP, AWQ group
@@ -137,6 +145,53 @@ def test_pool_update_compiles_in_place(one_chip, name):
     compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
     pool_bytes = 28 * 4096 * BS * KVH * DH * 2
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 100
+
+
+@pytest.mark.parametrize("step", ["decode", "chunk"])
+def test_step_reads_stacked_pool_in_place(one_chip, step):
+    """The engine's decode and chunk-prefill step programs hand the walks
+    the stacked pool, not ``pool[layer]``: a Pallas operand is a buffer of
+    its own, so a layer slice made XLA copy the layer out whole before each
+    call. At 20,629 blocks (the one-chip benchmark's pool) such a slice is
+    169 MB; a 4096-block slice lands in another memory space and shows no
+    temporaries, so the test sizes the pool as served."""
+    n_layers, cap, C = 2, 20629, 512
+    cfg = dataclasses.replace(QWEN2_1P5B, n_layers=n_layers)
+    kinds = tuple(lm.layer_kinds(cfg))
+    specs = me.build_attention_specs(cfg, kinds)
+
+    def shaped(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    params = jax.eval_shape(lambda: lm.init_params(cfg, jax.random.PRNGKey(0)))
+    misc = shaped({k: v for k, v in params.items() if k != "segments"})
+    layers = shaped(jax.eval_shape(lambda: tuple(
+        lm.block_init(kind, jax.random.PRNGKey(i), cfg, jnp.bfloat16)
+        for i, kind in enumerate(kinds))))
+    pool = jax.ShapeDtypeStruct((n_layers, cap, BS, KVH, DH), jnp.bfloat16,
+                                sharding=one_chip)
+    if step == "decode":
+        no_ssm = jax.ShapeDtypeStruct((0,), jnp.float32, sharding=one_chip)
+        fn = functools.partial(me.paged_decode_step, cfg, kinds, specs)
+        args = (misc, layers, i32(SLOTS, 1), i32(SLOTS), pool, pool,
+                i32(SLOTS, MAX_NB), no_ssm, no_ssm)
+    else:
+        fn = functools.partial(me.paged_prefill_chunk, cfg, kinds, specs)
+        args = (misc, layers, i32(1, C), i32(), pool, pool, i32(MAX_NB))
+    prev = dispatch.set_mode("pallas")
+    try:
+        compiled = jax.jit(fn, donate_argnums=(4, 5)).lower(*args).compile()
+    finally:
+        dispatch.set_mode(prev)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    layer_bytes = cap * BS * KVH * DH * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes // 100
+    layer_shape = rf"= \w+\[{cap},{BS},{KVH},{DH}\]"
+    assert not re.findall(layer_shape, text)
 
 
 @pytest.mark.parametrize("bits", [4, 8])
