@@ -1,4 +1,5 @@
-"""Operations and bytes the served model needs, from its shapes.
+"""Operations and bytes the served model needs, from its shapes, as its
+architecture module (``cfg["arch"]``) counts them.
 
 These are what the algorithm requires, not what a kernel happens to do:
 padding rows, idle decode slots and re-reads are not counted, so a kernel
@@ -8,52 +9,41 @@ from __future__ import annotations
 
 from typing import Iterable, Tuple
 
-from bench.lib import weights as W
-
 
 def matmul_params(cfg: dict) -> int:
-    """Parameters one token multiplies through: every layer's projections
+    """Parameters one token multiplies through: those its layers touch
     (int4 layers count at the model's size) plus the output head."""
-    z = W.dims(cfg)
-    D, H, KVH, Dh, F, V, L = (z[k] for k in ("D", "H", "KVH", "Dh", "F",
-                                             "V", "L"))
-    per_layer = D * (H + 2 * KVH) * Dh + H * Dh * D + 3 * D * F
-    return L * per_layer + D * V
-
-
-def attn_flops(cfg: dict, n_query: int, ctx_before: int) -> int:
-    """QK^T and PV of ``n_query`` causal queries starting at position
-    ``ctx_before``, over all layers: 4 flops per (query, key, head, dim)."""
-    z = W.dims(cfg)
-    pairs = n_query * ctx_before + n_query * (n_query + 1) // 2
-    return 4 * z["L"] * z["H"] * z["Dh"] * pairs
+    arch = cfg["arch"]
+    return arch.touched_params(cfg) + arch.head_params(cfg)
 
 
 def step_flops(cfg: dict, decode_ctx: Iterable[int],
                chunks: Iterable[Tuple[int, int]], logits_rows: int) -> int:
-    """FLOPs one engine step requires: 2 per parameter per token through the
-    layers, attention over each token's context, and the head only for the
-    rows whose logits are used (one per decode row and per prompt end).
+    """FLOPs one engine step requires: 2 per parameter a token touches
+    through the layers, attention over each token's context, and the head
+    only for the rows whose logits are used (one per decode row and per
+    prompt end).
 
     ``decode_ctx``: context length before each decoded token;
     ``chunks``: (start position, tokens) of each prefill piece."""
-    z = W.dims(cfg)
-    layer_params = matmul_params(cfg) - z["D"] * z["V"]
+    arch = cfg["arch"]
     decode_ctx = list(decode_ctx)
     chunks = list(chunks)
     tokens = len(decode_ctx) + sum(n for _, n in chunks)
-    f = 2 * layer_params * tokens + 2 * z["D"] * z["V"] * logits_rows
-    f += sum(attn_flops(cfg, 1, c) for c in decode_ctx)
-    f += sum(attn_flops(cfg, n, p0) for p0, n in chunks)
+    f = 2 * arch.touched_params(cfg) * tokens \
+        + 2 * arch.head_params(cfg) * logits_rows
+    f += sum(arch.attn_flops(cfg, 1, c) for c in decode_ctx)
+    f += sum(arch.attn_flops(cfg, n, p0) for p0, n in chunks)
     return f
 
 
-def _block_bytes(cfg: dict, quantized: bool) -> int:
-    """One stored KV block of one layer, K and V: bf16 values, or int8
-    payload plus the four f32 scale/zero numbers of the block."""
-    z = W.dims(cfg)
-    n = cfg["serving"]["kv_block_size"] * z["KVH"] * z["Dh"]
-    return 2 * n + 16 if quantized else 2 * 2 * n
+def _blocks_bytes(cfg: dict, ctx: int, nq: int) -> int:
+    """The stored blocks of ``ctx`` positions, ``nq`` of them quantized,
+    each read once at its stored precision."""
+    arch = cfg["arch"]
+    blocks = -(-ctx // cfg["serving"]["kv_block_size"])
+    return (blocks - nq) * arch.kv_read_bytes(cfg, False) \
+        + nq * arch.kv_read_bytes(cfg, True)
 
 
 def decode_attn_cost(cfg: dict, rows: Iterable[Tuple[int, int]]
@@ -62,16 +52,12 @@ def decode_attn_cost(cfg: dict, rows: Iterable[Tuple[int, int]]
     one step. ``rows``: (context length before the new token, blocks of it
     held quantized) per live row. Each live block is read once at its stored
     precision; the query, the new token's K/V and the output once each."""
-    z = W.dims(cfg)
-    bs = cfg["serving"]["kv_block_size"]
-    L, H, KVH, Dh = z["L"], z["H"], z["KVH"], z["Dh"]
+    arch = cfg["arch"]
+    io = arch.decode_io_bytes(cfg)
     flops = nbytes = 0
     for ctx, nq in rows:
-        flops += attn_flops(cfg, 1, ctx)
-        blocks = -(-ctx // bs)
-        kv = (blocks - nq) * _block_bytes(cfg, False) \
-            + nq * _block_bytes(cfg, True)
-        nbytes += L * (kv + 2 * (2 * H * Dh) + 2 * (2 * KVH * Dh))
+        flops += arch.attn_flops(cfg, 1, ctx)
+        nbytes += _blocks_bytes(cfg, ctx, nq) + io
     return flops, nbytes
 
 
@@ -81,16 +67,12 @@ def chunk_attn_cost(cfg: dict, chunks: Iterable[Tuple[int, int, int]]
     ``(start, tokens, quantized context blocks)``: causal attention of the
     piece over its context and itself; the context's blocks read once at
     their stored precision, the piece's q, k, v and output once each."""
-    z = W.dims(cfg)
-    bs = cfg["serving"]["kv_block_size"]
-    L, H, KVH, Dh = z["L"], z["H"], z["KVH"], z["Dh"]
+    arch = cfg["arch"]
+    io = arch.chunk_io_bytes(cfg)
     flops = nbytes = 0
     for p0, n, nq in chunks:
-        flops += attn_flops(cfg, n, p0)
-        blocks = -(-p0 // bs)
-        kv = (blocks - nq) * _block_bytes(cfg, False) \
-            + nq * _block_bytes(cfg, True)
-        nbytes += L * (kv + n * 2 * (2 * H * Dh + 2 * KVH * Dh))
+        flops += arch.attn_flops(cfg, n, p0)
+        nbytes += _blocks_bytes(cfg, p0, nq) + n * io
     return flops, nbytes
 
 

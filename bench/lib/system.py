@@ -1,9 +1,9 @@
-"""The system under test: the only module of the benchmark that imports the
-program (``src/repro``).
+"""The system under test: with the architecture modules' ``model_config``,
+the only code of the benchmark that imports the program (``src/repro``).
 
-It turns a configuration file into the program's ``ModelConfig``, hands the
-benchmark's weights to the program in its layout, sizes the HBM budget to
-the chip, builds ``MorphServeEngine`` as a deployment would run it
+It checks the parameter tree the architecture module hands the program
+against the one the program builds itself, sizes the HBM budget to the
+chip, builds ``MorphServeEngine`` as a deployment would run it
 (``compute="real"``, the configuration's policy, Pallas kernels) and runs
 once every step program a cell's traffic can reach, through the program's
 public entry points.
@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import sys
 from pathlib import Path
-from typing import Dict, List
+from typing import List
 
 ROOT = Path(__file__).resolve().parents[2]
 if str(ROOT / "src") not in sys.path:
@@ -22,47 +22,11 @@ import jax                                             # noqa: E402
 import numpy as np                                     # noqa: E402
 import jax.numpy as jnp                                # noqa: E402
 
-from bench.lib import weights as W                     # noqa: E402
-
-
-def model_config(cfg: dict):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.configs.base import ModelConfig
-    z = W.dims(cfg)
-    arch = cfg["architecture"]
-    return ModelConfig(
-        name=cfg["name"], family="dense", n_layers=z["L"], d_model=z["D"],
-        n_heads=z["H"], n_kv_heads=z["KVH"], d_ff=z["F"], vocab=z["V"],
-        head_dim=z["Dh"], norm=arch["norm"], act=cfg["hidden_act"],
-        gated_mlp=True, qkv_bias=arch["qkv_bias"],
-        tie_embeddings=cfg["tie_word_embeddings"],
-        rope_theta=float(cfg["rope_theta"]),
-        dtype=cfg["serving"]["dtype"], source=cfg["source"])
-
-
-def program_params(cfg: dict, w: Dict[str, jax.Array]) -> dict:
-    """The benchmark's weights in the program's parameter tree (the same
-    device arrays, no copy): one segment of stacked dense layers."""
-    rms = cfg["architecture"]["norm"] == "rmsnorm"
-    attn = {k: w[k] for k in ("wq", "wk", "wv", "wo")}
-    for b in ("bq", "bk", "bv"):
-        if b in w:
-            attn[b] = w[b]
-    layer = {"ln1": {"scale": w["ln1"]} if rms else {}, "attn": attn,
-             "ln2": {"scale": w["ln2"]} if rms else {},
-             "mlp": {k: w[k] for k in ("w_up", "w_down", "w_gate")}}
-    params = {"embed": w["embed"],
-              "final_norm": {"scale": w["final_norm"]} if rms else {},
-              "segments": [(layer,)]}
-    if "lm_head" in w:
-        params["lm_head"] = w["lm_head"]
-    return params
-
 
 def check_layout(cfg: dict, params: dict) -> None:
     """The tree handed over must be the one the program builds itself."""
     from repro.models import lm
-    mc = model_config(cfg)
+    mc = cfg["arch"].model_config(cfg)
     want = jax.eval_shape(lambda k: lm.init_params(mc, k),
                           jax.random.PRNGKey(0))
     got = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
@@ -84,36 +48,6 @@ def _pow2(n: int) -> int:
     return b
 
 
-def weight_bytes(cfg: dict) -> dict:
-    """Bytes the program holds for the weights: per fp layer (bf16), per
-    int4 layer (packed nibbles plus f32 scale and zero per group of 128
-    along K, the rest fp), and the embedding / head / final norm."""
-    z = W.dims(cfg)
-    group = cfg["serving"]["swap_group"]
-    fp = q = 0
-    for name, shape in W.shapes(cfg).items():
-        if name in ("embed", "lm_head", "final_norm"):
-            continue
-        n = 1
-        for s in shape[1:]:
-            n *= s
-        fp += 2 * n
-        if len(shape) == 3 and n >= 1 << 14:
-            k, m = shape[1], shape[2]
-            q += k // 2 * m + 2 * (k // group) * m * 4
-        else:
-            q += 2 * n
-    misc = 2 * z["V"] * z["D"] * (1 if cfg["tie_word_embeddings"] else 2)
-    if cfg["architecture"]["norm"] == "rmsnorm":
-        misc += 2 * z["D"]
-    return {"fp_layer": fp, "q_layer": q, "misc": misc, "L": z["L"]}
-
-
-def kv_block_bytes(cfg: dict) -> int:
-    z = W.dims(cfg)
-    return z["L"] * cfg["serving"]["kv_block_size"] * 2 * z["KVH"] * z["Dh"] * 2
-
-
 def size_budget(cfg: dict, bytes_limit: int) -> dict:
     """The engine's ``hbm_budget_bytes`` for a chip of ``bytes_limit``.
 
@@ -133,16 +67,17 @@ def size_budget(cfg: dict, bytes_limit: int) -> dict:
     the pool's start and largest capacity share one power-of-two bucket, so
     that no resize changes the pool's shape and no program recompiles."""
     s = cfg["serving"]
-    wb = weight_bytes(cfg)
-    L = wb["L"]
-    blk = kv_block_bytes(cfg)
-    w0 = L * wb["fp_layer"]
-    w_min = L * wb["q_layer"]
+    layers = cfg["arch"].layer_bytes(cfg)
+    misc = cfg["arch"].misc_bytes(cfg)
+    L = len(layers)
+    blk = sum(x["kv"] for x in layers)
+    w0 = sum(x["fp"] for x in layers)
+    w_min = sum(x["q"] for x in layers)
     if s["kv_quant"]:
         per_block = blk * (1.5 + s["kv_quant_step_temp_share"]) + 16 * L
     else:
         per_block = blk * (1.0 + s["step_temp_share"])
-    fixed = w0 + w_min + wb["misc"]
+    fixed = w0 + w_min + misc
     room = bytes_limit - s["headroom_bytes"]
     # while the engine is built, the stacked weights handed to it are still
     # alive beside its per-layer copies and the new pool arrays
@@ -160,7 +95,7 @@ def size_budget(cfg: dict, bytes_limit: int) -> dict:
         raise ValueError(f"{bytes_limit} bytes hold no KV pool beside the "
                          "weights")
     # the engine: start = (0.95 B - misc - w0) // blk
-    budget = int((start * blk + w0 + wb["misc"]) / 0.95) + 2
+    budget = int((start * blk + w0 + misc) / 0.95) + 2
     return {"hbm_budget_bytes": budget, "start_blocks": start,
             "capacity": most, "block_bytes": blk,
             "device_bytes": int(fixed + most * per_block)}
@@ -173,7 +108,7 @@ def build_engine(cfg: dict, cell: dict, params: dict, budget: int):
     from repro.configs.base import ServingConfig
     from repro.engine import EngineConfig, KVQuantConfig, MorphServeEngine
     s = cfg["serving"]
-    mc = model_config(cfg)
+    mc = cfg["arch"].model_config(cfg)
     sc = ServingConfig(hbm_budget_bytes=budget,
                        kv_block_size=s["kv_block_size"],
                        max_batch_slots=cell["max_batch_slots"],

@@ -5,7 +5,8 @@
 
 Everything a cell needs is found by name: the cell ``bench/cells/<cell>.json``
 (configuration, traffic, rate, limits), its configuration file (named in
-``BENCHMARK.json``), the traffic mix ``bench/traffic/<mix>.json`` and, with
+``BENCHMARK.json``), the architecture module ``bench/arch/<module>.py`` that
+file names, the traffic mix ``bench/traffic/<mix>.json`` and, with
 ``--trace 1``, one reader ``bench/metrics/<metric>.py`` per per-layer metric.
 
 The run makes the weights from the seed on the chip, builds the engine with
@@ -37,6 +38,8 @@ ROOT = Path(__file__).resolve().parents[1]
 if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
+from bench.lib import arch                             # noqa: E402
+
 # the persistent compilation cache lives inside the checkout, at a fixed path
 CACHE_DIR = ROOT / ".jax_cache"
 OUT_DIR = ROOT / "bench_out"
@@ -60,7 +63,8 @@ def load_spec(workload: str, root: Path = ROOT) -> dict:
         raise KeyError(f"no workload {workload!r} in BENCHMARK.json")
     w = cells[workload]
     configs = {c["name"]: c for c in bench["configs"]}
-    cfg = json.loads((root / configs[w["config"]]["file"]).read_text())
+    cfg = arch.bind(json.loads((root / configs[w["config"]]["file"])
+                               .read_text()), root)
     cell = json.loads((root / "bench" / "cells" / f"{workload}.json")
                       .read_text())
     mix = json.loads((root / "bench" / "traffic" / f"{w['traffic']}.json")
@@ -137,7 +141,7 @@ def setup(spec: dict, seed: int, device, tamper=None):
         or cell.get("bytes_limit")
     size = system.size_budget(cfg, limit)
     t = time.perf_counter()
-    params = system.program_params(cfg, w)
+    params = cfg["arch"].program_params(cfg, w)
     system.check_layout(cfg, params)
     eng = system.build_engine(cfg, cell, params, size["hbm_budget_bytes"])
     del w, params
@@ -252,6 +256,15 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool, *,
     log(f"[{name}] step ms at p50/p90/p95/p99 "
         f"{[stats.nearest_rank(step_ms, q) for q in qs]}; inter-token gap ms "
         f"{[stats.nearest_rank(gap_ms, q) for q in qs]}")
+    # the pool's usage over the window's steps: the engine's Telemetry, one
+    # record per step
+    mark = eng.sc.kv_pressure_high
+    hist = eng.monitor.history[len(eng.monitor.history) - len(win.steps):]
+    high_s = sum(s.t1 - s.t0 for s, t in zip(win.steps, hist)
+                 if start <= s.t0 < end and t.kv_usage >= mark)
+    log(f"[{name}] KV usage at or over {mark} of the pool for {high_s:.3f} "
+        f"of {sum(s.t1 - s.t0 for s in in_window):.3f} step seconds in the "
+        f"window")
     log(f"[{name}] window: {e2e['n_due']} requests due, {e2e['n_failed']} "
         f"failed, {e2e['n_gaps']} gaps, {e2e['n_decided']} decided for "
         f"attainment; generator at most {win.max_late_s * 1e3:.3f} ms late; "

@@ -14,25 +14,14 @@ import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
 from bench import run as R  # noqa: E402
-from bench.lib import flops, stats, traffic  # noqa: E402
+from bench.lib import arch, flops, stats, traffic  # noqa: E402
 
 ROOT = Path(__file__).resolve().parents[2]
 
 
 def cfg(name):
-    if name == "olmo-1b":
-        return OLMO
-    return json.loads((ROOT / "bench" / "configs" / f"{name}.json")
-                      .read_text())
-
-
-# OLMo-1B's widths (arXiv:2402.00838): an MHA model beside the GQA one
-OLMO = {"hidden_size": 2048, "intermediate_size": 8192,
-        "num_hidden_layers": 16, "num_attention_heads": 16,
-        "num_key_value_heads": 16, "vocab_size": 50304,
-        "tie_word_embeddings": True,
-        "architecture": {"norm": "nonparam_ln", "qkv_bias": False},
-        "serving": {"kv_block_size": 16}}
+    return arch.bind(json.loads((ROOT / "bench" / "configs" / f"{name}.json")
+                                .read_text()))
 
 
 # --- FLOPs and bytes --------------------------------------------------------
@@ -99,6 +88,23 @@ def test_step_mfu_over_all_steps_and_prefill_steps():
            for n in ("step.mfu", "step.mfu.prefill")}
     assert got["step.mfu"] == pytest.approx(100 * (f_dec + f_pre) / 1e12)
     assert got["step.mfu.prefill"] == pytest.approx(100 * f_pre / 0.5e12)
+
+
+def test_relief_readers_weight_levels_by_wall_time_and_count_events():
+    def step(t0, dt, level, events):
+        return types.SimpleNamespace(t0=t0, t1=t0 + dt, level=level,
+                                     events=events)
+
+    ctx = types.SimpleNamespace(steps=[step(0.0, 1.0, 0, 1),
+                                       step(1.0, 3.0, 8, 0),
+                                       step(4.0, 1.0, 4, 2)])
+    got = {n: R.load_reader(n)(ctx)
+           for n in ("relief.level_mean", "relief.events")}
+    assert got["relief.level_mean"] == pytest.approx((0 + 24 + 4) / 5.0)
+    assert got["relief.events"] == 3
+    empty = types.SimpleNamespace(steps=[])
+    assert R.load_reader("relief.level_mean")(empty) is None
+    assert R.load_reader("relief.events")(empty) is None
 
 
 def test_roofline_takes_the_larger_bound():

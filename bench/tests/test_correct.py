@@ -17,6 +17,7 @@ import jax  # noqa: E402
 import pytest  # noqa: E402
 
 from bench import run as R  # noqa: E402
+from bench.lib import arch  # noqa: E402
 
 CFG = {"name": "tiny", "source": "test",
        "hidden_size": 128, "intermediate_size": 256, "num_hidden_layers": 2,
@@ -24,7 +25,8 @@ CFG = {"name": "tiny", "source": "test",
        "vocab_size": 512, "rope_theta": 1000000.0, "hidden_act": "silu",
        "tie_word_embeddings": True,
        "rms_norm_eps": 1e-05,
-       "architecture": {"norm": "rmsnorm", "qkv_bias": True},
+       "architecture": {"module": "dense", "norm": "rmsnorm",
+                        "qkv_bias": True},
        "serving": {"dtype": "bfloat16", "policy": "morph",
                    "mode": "accuracy", "swap_bits": 4, "swap_group": 128,
                    "kv_quant_bits": 8,
@@ -34,6 +36,7 @@ CFG = {"name": "tiny", "source": "test",
                    "swap_levels": [0, 1], "decode_nb_bucketing": False,
                    "kv_quant": True,
                    "min_chunk_tokens": 64}}
+CFG = arch.bind(CFG)
 MIX = {"shape_seed": 1,
        "prompt": {"median": 24, "sigma": 0.5, "min": 8, "max": 60},
        "output": {"median": 12, "sigma": 0.3, "min": 6, "max": 20}}
@@ -78,3 +81,21 @@ def test_altered_token_is_not_correct():
         eng._decode_real = decode
     res = run(12, tamper=tamper)
     assert not res["correct"], res["checks"]
+
+
+def pin_level_1(eng):
+    """Serve every step at swap level 1: layer 0 on its int4 weights."""
+    eng._pinned_level = 1
+    eng.actuator.level = 1
+    eng.controller.commit(1)
+
+
+@pytest.mark.parametrize("control", [False, True])
+def test_swapped_level_sound_is_correct_and_control_is_not(control, capsys):
+    """At a swapped level the program's int4 tokens pass; the control, whose
+    int4 has one scale per output column instead of one per group of 128,
+    does not. Read on the CPU at this size, seeds 13-15: the program
+    0-0.0063, the control 0.040-0.068."""
+    res = run(13, control=control, tamper=pin_level_1)
+    assert "levels [1]," in capsys.readouterr().err
+    assert res["correct"] is not control, res["checks"]
